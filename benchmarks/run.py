@@ -72,6 +72,8 @@ def main(argv=None) -> None:
                     help="--compare flags probes whose us_per_call moved "
                          "more than this many percent (default 35)")
     args = ap.parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     results: dict[str, dict] = {}
 

@@ -280,6 +280,7 @@ def eval_mask(pred: Predicate, tbl: dict,
     names = list(ranges)
     bounds = [_f32_shrink(*ranges[c]) for c in names]
     cols = np.stack([np.asarray(tbl[c], np.float32) for c in names])
+    _metrics.counter("bullion.filter.kernel_calls").inc()
     return range_mask(cols,
                       np.asarray([b[0] for b in bounds], np.float32),
                       np.asarray([b[1] for b in bounds], np.float32))

@@ -8,7 +8,22 @@
   flash_attention — blocked online-softmax attention (beyond-paper training
                     perf; the §Perf answer to vanilla attention's HBM traffic)
 
-Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper; interpret=True on CPU), ref.py (pure-jnp oracle). The TPU container
-is CPU-only, so correctness is validated in interpret mode.
+Each kernel ships kernel.py (pl.pallas_call + BlockSpec, with ``interpret``
+a static argument), ops.py (the public wrapper) and ref.py (pure-jnp
+oracle). The wrappers take no ``interpret`` option: on a TPU backend every
+kernel compiles through Mosaic, on any other backend (the CPU the tests run
+on) it runs in the Pallas interpreter. ``tests/test_tpu_compile.py`` compiles
+the main-path kernels for a described v5e with the interpreter off.
 """
+
+from __future__ import annotations
+
+import functools
+
+import jax
+
+
+@functools.cache
+def interpret() -> bool:
+    """True unless JAX's default backend is a TPU, decided once per process."""
+    return jax.default_backend() != "tpu"

@@ -31,7 +31,7 @@ def _kernel(planes_ref, out_ref, *, width: int):
 
 @functools.partial(jax.jit, static_argnames=("width", "interpret"))
 def bitunpack_pallas(planes: jax.Array, width: int,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """planes: uint32[G, w] (G % GROUPS_PER_BLOCK == 0) -> uint32[G, 32]."""
     G = planes.shape[0]
     grid = (G // GROUPS_PER_BLOCK,)

@@ -5,6 +5,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from .. import interpret
 from .kernel import GROUPS_PER_BLOCK, bitunpack_pallas
 from .ref import pack_bp32_ref
 
@@ -17,10 +18,9 @@ def pack_bp32(values: np.ndarray, width: int) -> np.ndarray:
     return pack_bp32_ref(v, width)
 
 
-def bitunpack(planes, width: int, n_values: int | None = None,
-              interpret: bool = True):
+def bitunpack(planes, width: int, n_values: int | None = None):
     """Device-side unpack: uint32[G, w] -> uint32[n_values]."""
-    out = bitunpack_pallas(jnp.asarray(planes), width, interpret=interpret)
+    out = bitunpack_pallas(jnp.asarray(planes), width, interpret=interpret())
     flat = out.reshape(-1)
     if n_values is not None:
         flat = flat[:n_values]

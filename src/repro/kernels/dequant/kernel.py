@@ -5,8 +5,10 @@ HBM straight from Bullion pages; the kernel fuses (dequantize, scale, cast)
 into a single VMEM pass so the FP32 intermediate never exists — feeding
 embeddings/features to the model at storage precision.
 
-Grid tiles (rows, features); per-feature scale/zero tiles ride along the
-feature axis only (index_map pins the row coordinate).
+Grid tiles (rows, features); per-feature scale/zero ride along the feature
+axis only (index_map pins the row coordinate) as (1, BLOCK_C) tiles of a
+[1, C] row: Mosaic lays out a 1-D f32 block in 128-lane tiles where XLA
+uses 256, and refuses the operand.
 """
 
 from __future__ import annotations
@@ -28,14 +30,15 @@ def _kernel(q_ref, scale_ref, zero_ref, out_ref, *, from_bf16_bits: bool,
         f = jax.lax.bitcast_convert_type(q.astype(jnp.uint32) << 16,
                                          jnp.float32)
     else:
-        f = q.astype(jnp.float32) * scale_ref[...][None, :] \
-            + zero_ref[...][None, :]
+        f = q.astype(jnp.float32) * scale_ref[...] + zero_ref[...]
     out_ref[...] = f.astype(out_dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("out_dtype", "interpret"))
-def dequant_pallas(q, scale, zero, out_dtype=jnp.bfloat16, interpret=True):
+def dequant_pallas(q, scale, zero, out_dtype=jnp.bfloat16, *,
+                   interpret: bool):
+    """q: [R, C] (R % BLOCK_R == 0, C % BLOCK_C == 0); scale, zero: f32[C]."""
     R, C = q.shape
     assert R % BLOCK_R == 0 and C % BLOCK_C == 0, (R, C)
     from_bf16 = q.dtype == jnp.uint16
@@ -45,10 +48,10 @@ def dequant_pallas(q, scale, zero, out_dtype=jnp.bfloat16, interpret=True):
         grid=(R // BLOCK_R, C // BLOCK_C),
         in_specs=[
             pl.BlockSpec((BLOCK_R, BLOCK_C), lambda r, c: (r, c)),
-            pl.BlockSpec((BLOCK_C,), lambda r, c: (c,)),
-            pl.BlockSpec((BLOCK_C,), lambda r, c: (c,)),
+            pl.BlockSpec((1, BLOCK_C), lambda r, c: (0, c)),
+            pl.BlockSpec((1, BLOCK_C), lambda r, c: (0, c)),
         ],
         out_specs=pl.BlockSpec((BLOCK_R, BLOCK_C), lambda r, c: (r, c)),
         out_shape=jax.ShapeDtypeStruct((R, C), out_dtype),
         interpret=interpret,
-    )(q, scale, zero)
+    )(q, scale.reshape(1, C), zero.reshape(1, C))

@@ -29,7 +29,7 @@ def _kernel(cols_ref, lo_ref, hi_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def range_mask_pallas(cols: jax.Array, lo: jax.Array, hi: jax.Array,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool) -> jax.Array:
     """cols: f32[C, N] (N % BLOCK_N == 0); lo, hi: f32[C] -> uint8[1, N]."""
     C, N = cols.shape
     grid = (N // BLOCK_N,)

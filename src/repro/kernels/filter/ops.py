@@ -5,11 +5,11 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from .. import interpret
 from .kernel import BLOCK_N, range_mask_pallas
 
 
-def range_mask(cols, lo, hi, n_values: int | None = None,
-               interpret: bool = True) -> np.ndarray:
+def range_mask(cols, lo, hi, n_values: int | None = None) -> np.ndarray:
     """Conjunctive range filter: f32[C, N] columns -> bool[N] survivor mask.
 
     Pads the row axis to a BLOCK_N multiple (padding rows are sliced back
@@ -25,5 +25,5 @@ def range_mask(cols, lo, hi, n_values: int | None = None,
     out = range_mask_pallas(jnp.asarray(cols),
                             jnp.asarray(lo, jnp.float32),
                             jnp.asarray(hi, jnp.float32),
-                            interpret=interpret)
+                            interpret=interpret())
     return np.asarray(out).reshape(-1)[:n_values].astype(bool)

@@ -89,7 +89,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                    static_argnames=("causal", "window", "kv_len", "d_real",
                                     "interpret"))
 def flash_attention_pallas(q, k, v, *, causal=True, window=0,
-                           kv_len=None, d_real=None, interpret=True):
+                           kv_len=None, d_real=None, interpret: bool):
     """q,k,v: [BH, S, D] with S % BLOCK == 0, D % 128 == 0.
     kv_len: number of real (non-padded) keys; d_real: real head_dim for the
     softmax scale."""

@@ -4,10 +4,11 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from .. import interpret
 from .kernel import BLOCK_K, BLOCK_Q, flash_attention_pallas
 
 
-def flash_attention(q, k, v, *, causal=True, window=0, interpret=True):
+def flash_attention(q, k, v, *, causal=True, window=0):
     """q,k,v: [B, H, S, D] -> [B, H, S, D]. S padded to 128, D padded to 128.
 
     Padded keys are masked out by the causal mask for padded queries and by
@@ -23,5 +24,5 @@ def flash_attention(q, k, v, *, causal=True, window=0, interpret=True):
 
     qp, kp, vp = prep(q), prep(k), prep(v)
     out = flash_attention_pallas(qp, kp, vp, causal=causal, window=window,
-                                 kv_len=S, d_real=D, interpret=interpret)
+                                 kv_len=S, d_real=D, interpret=interpret())
     return out.reshape(B, H, Sp, Dp)[:, :, :S, :D]

@@ -1,15 +1,22 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input-shape) cell
 on the production meshes and record memory/cost/collective artifacts.
 
   PYTHONPATH=src python -m repro.launch.dryrun --arch llama3.2-1b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod]
 
-Artifacts land in artifacts/dryrun/<arch>__<shape>__<mesh>.json and feed the
-roofline table in EXPERIMENTS.md.
+Run as a script it gives the CPU backend 512 host devices; a process that
+imports it sets ``--xla_force_host_platform_device_count`` itself before
+JAX starts. Artifacts land in artifacts/dryrun/<arch>__<shape>__<mesh>.json
+and feed the roofline table in EXPERIMENTS.md.
 """
+
+import os
+
+if __name__ == "__main__":
+    # before jax initializes its backend; added to, never overwriting, flags
+    # the caller set
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               " --xla_force_host_platform_device_count=512")
 
 import argparse
 import dataclasses

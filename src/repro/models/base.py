@@ -19,7 +19,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec
+from jax.sharding import NamedSharding, PartitionSpec
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,9 +120,10 @@ def spec_tree(decl, rules: ShardingRules, mesh=None):
     return jax.tree.map(leaf, decl, is_leaf=is_decl)
 
 
-def constrain(x, rules: ShardingRules, axes: tuple[Optional[str], ...]):
-    """with_sharding_constraint by logical axes (no-op without a mesh)."""
-    try:
-        return jax.lax.with_sharding_constraint(x, rules.spec_for(axes))
-    except (ValueError, RuntimeError):
-        return x  # no mesh context (single-device smoke tests)
+def constrain(x, dist, axes: tuple[Optional[str], ...]):
+    """with_sharding_constraint by logical axes on ``dist``'s mesh; a no-op
+    when there is no mesh (single-device runs)."""
+    if dist is None or dist.mesh is None:
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(dist.mesh, dist.rules.spec_for(axes)))

@@ -26,11 +26,6 @@ from jax.sharding import PartitionSpec as PS
 
 from .base import P
 
-try:  # jax >= 0.7 exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 PRODUCTION_M = 16  # model-axis size of the production mesh (chunk layout)
 
 
@@ -189,10 +184,6 @@ def moe_block(p, x, cfg, dist=None):
     pspec, xspec = moe_specs(p, cfg, mesh, batch_axes)
     all_axes = tuple(mesh.axis_names)
     fn = partial(moe_apply, cfg=cfg, model_axis="model", all_axes=all_axes)
-    try:
-        smapped = _shard_map(fn, mesh=mesh, in_specs=(pspec, xspec),
-                             out_specs=(xspec, PS()), check_vma=False)
-    except TypeError:  # older jax: check_rep
-        smapped = _shard_map(fn, mesh=mesh, in_specs=(pspec, xspec),
-                             out_specs=(xspec, PS()), check_rep=False)
+    smapped = jax.shard_map(fn, mesh=mesh, in_specs=(pspec, xspec),
+                            out_specs=(xspec, PS()), check_vma=False)
     return smapped(p, x)

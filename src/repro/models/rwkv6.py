@@ -163,10 +163,10 @@ def rwkv_block(p, x, cache=None, *, cfg, use_chunked=False, dist=None):
         # model-sharded here, or every scan step emits an all-gather (the
         # §Perf rwkv baseline pathology — one collective per token step)
         spec = ("batch", None, "heads", None)
-        r = constrain(r, dist.rules, spec)
-        k = constrain(k, dist.rules, spec)
-        v = constrain(v, dist.rules, spec)
-        w = constrain(w, dist.rules, spec)
+        r = constrain(r, dist, spec)
+        k = constrain(k, dist, spec)
+        v = constrain(v, dist, spec)
+        w = constrain(w, dist, spec)
 
     state = cache["S"] if cache is not None else jnp.zeros((B, H, dh, dh), jnp.float32)
     u = tm["u"].astype(jnp.float32)

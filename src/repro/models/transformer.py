@@ -262,9 +262,7 @@ def logits_fn(params, x, cfg):
 
 def forward(params, x, cfg: ModelConfig, ctx: Ctx, cache=None):
     """x: [B, T, d] embedded inputs. Returns (hidden, new_cache, aux)."""
-    rules = ctx.dist.rules if ctx.dist is not None else None
-    if rules is not None:
-        x = constrain(x, rules, ("batch", "seq", None))
+    x = constrain(x, ctx.dist, ("batch", "seq", None))
     aux_total = jnp.zeros((), jnp.float32)
     new_segments = []
     for si, (blocks, rep) in enumerate(cfg.segments):
@@ -283,8 +281,7 @@ def forward(params, x, cfg: ModelConfig, ctx: Ctx, cache=None):
                 h, nc, aux = apply_block(ps[f"b{j}"], h, b, ctx, c_j)
                 if nc is not None:
                     new_cs[f"b{j}"] = nc
-            if rules is not None:
-                h = constrain(h, rules, ("batch", "seq", None))
+            h = constrain(h, ctx.dist, ("batch", "seq", None))
             out_cs = new_cs if seg_cache is not None else None
             return (h, aux_c + aux), out_cs
 

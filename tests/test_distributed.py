@@ -31,6 +31,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.models import zoo
         from repro.models.base import spec_tree
         from repro.distributed import make_dist
+        from repro.launch.mesh import make_mesh
         from repro.train import AdamWConfig, adamw_init, make_train_step
 
         cfg = configs.get_smoke("llama3_2_1b").scaled(compute_dtype="float32")
@@ -45,7 +46,7 @@ def test_sharded_train_step_matches_single_device():
         p0b, o0b, met0 = s0(p0, o0, batch)
 
         # sharded
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         dist = make_dist(mesh)
         m1 = zoo.build(cfg, dist)
         specs = spec_tree(m1.decl, dist.rules, mesh)
@@ -77,6 +78,7 @@ def test_moe_shard_map_matches_local():
         from repro.models import zoo
         from repro.models.base import spec_tree
         from repro.distributed import make_dist
+        from repro.launch.mesh import make_mesh
 
         for arch in ("mixtral_8x22b", "deepseek_moe_16b"):
             cfg = configs.get_smoke(arch).scaled(compute_dtype="float32",
@@ -87,7 +89,7 @@ def test_moe_shard_map_matches_local():
             p0 = m0.init(rng)
             l0 = float(jax.jit(m0.loss)(p0, batch))
 
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_mesh((2, 4), ("data", "model"))
             dist = make_dist(mesh)
             m1 = zoo.build(cfg, dist)
             specs = spec_tree(m1.decl, dist.rules, mesh)
@@ -121,6 +123,8 @@ def test_production_mesh_shapes():
 def test_dryrun_single_cell_small():
     """The dry-run path end-to-end on the real 512-device mesh (small arch)."""
     _run(textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
         from repro.launch.dryrun import run_cell
         import tempfile
         rec = run_cell("llama3.2-1b", "decode_32k", multi_pod=True,

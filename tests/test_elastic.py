@@ -18,12 +18,13 @@ def test_elastic_restore_roundtrip(tmp_path):
         from repro.models import zoo
         from repro.models.base import spec_tree
         from repro.distributed import make_dist
+        from repro.launch.mesh import make_mesh
         from repro.train.checkpoint import CheckpointManager
         from repro.train.elastic import elastic_restore, shardings_for
 
         cfg = configs.get_smoke("llama3_2_1b").scaled(compute_dtype="float32")
         m = zoo.build(cfg)
-        mesh8 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh8 = make_mesh((2, 4), ("data", "model"))
         sh8 = shardings_for(m.decl, mesh8)
         params = jax.tree.map(lambda t, s: jax.device_put(t, s),
                               m.init(jax.random.PRNGKey(0)), sh8)
@@ -32,7 +33,7 @@ def test_elastic_restore_roundtrip(tmp_path):
         mgr.save(5, params)
 
         # restore onto a *different* mesh (half the fleet)
-        mesh4 = jax.make_mesh((1, 4), ("data", "model"))
+        mesh4 = make_mesh((1, 4), ("data", "model"))
         restored, manifest = elastic_restore(mgr, params, m.decl, mesh4)
         assert manifest["step"] == 5
         for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(restored)):

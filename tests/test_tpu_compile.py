@@ -1,0 +1,75 @@
+"""Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e.
+
+The TPU compiler is installed even where no chip is attached: it compiles
+for a described ``v5e:2x2`` topology, with the interpreter off, and refuses
+what the chip's compiler would refuse (tiling, layouts, VMEM). Nothing runs,
+so these tests say nothing of results; ``tests/test_kernels.py`` checks those
+in interpret mode. The topology is described inside a fixture, never at
+import: only one process may load the TPU library at a time.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.bitunpack.kernel import bitunpack_pallas
+from repro.kernels.dequant.kernel import dequant_pallas
+from repro.kernels.filter.kernel import range_mask_pallas
+
+N_ROWS = 65536          # one production row group
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a TPU program written to a persistent cache cannot be read back
+    # without a chip; keep such compiles out of any cache a test turned on
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile_text(fn, *shapes, sharding) -> str:
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    return fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("n_cols", [1, 3])
+def test_range_mask_compiles_for_v5e(one_chip, n_cols):
+    fn = jax.jit(lambda c, lo, hi: range_mask_pallas(c, lo, hi,
+                                                     interpret=False))
+    text = _compile_text(fn, ((n_cols, N_ROWS), jnp.float32),
+                         ((n_cols,), jnp.float32), ((n_cols,), jnp.float32),
+                         sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("q_dtype", [jnp.int8, jnp.uint16],
+                         ids=["int8_affine", "bf16_bits"])
+def test_dequant_compiles_for_v5e(one_chip, q_dtype):
+    fn = jax.jit(lambda q, s, z: dequant_pallas(q, s, z,
+                                                out_dtype=jnp.float32,
+                                                interpret=False))
+    text = _compile_text(fn, ((N_ROWS, 256), q_dtype),
+                         ((256,), jnp.float32), ((256,), jnp.float32),
+                         sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+def test_bitunpack_compiles_for_v5e(one_chip):
+    fn = jax.jit(lambda p: bitunpack_pallas(p, 7, interpret=False))
+    text = _compile_text(fn, ((2048, 7), jnp.uint32), sharding=one_chip)
+    assert "tpu_custom_call" in text

@@ -128,9 +128,10 @@ def test_elastic_reshard_plan():
         import repro.configs as configs
         from repro.models import zoo
         from repro.train.elastic import reshard_plan, shardings_for
+        from repro.launch.mesh import make_mesh
         m = zoo.build(configs.get_smoke("llama3_2_1b"))
-        mesh8 = jax.make_mesh((2, 4), ("data", "model"))
-        mesh4 = jax.make_mesh((1, 4), ("data", "model"))
+        mesh8 = make_mesh((2, 4), ("data", "model"))
+        mesh4 = make_mesh((1, 4), ("data", "model"))
         plan = reshard_plan(m.decl, mesh8, mesh4)
         assert plan["old_devices"] == 8 and plan["new_devices"] == 4
         sh = shardings_for(m.decl, mesh4)
