@@ -213,23 +213,15 @@ class BullionReader:
         """Positional read: ``os.pread`` (and its remote twin, one ranged
         GET) never moves a shared cursor, so concurrent ScanTasks on the
         same shard (parallel execution) are safe on one handle. Stats
-        mutate under a lock for the same reason. Per-call latency lands in
-        the ``bullion.io.pread_seconds`` histogram only while tracing is
-        enabled (two extra clock reads are not free on the disabled hot
-        path); remote handles charge ``backend_fetches``/``bytes_read``
-        themselves."""
+        mutate under a lock for the same reason. The caller's span times the
+        read (``io.run``, ``decode.pread``); remote handles charge
+        ``backend_fetches``/``bytes_read`` themselves."""
         h = self._handle
         if h is None:
             raise ValueError(f"{self.path}: reader is closed")
         if h.is_remote:
             return h.pread(offset, size)
-        if _trace.enabled():
-            t0 = time.perf_counter()
-            data = h.pread(offset, size)
-            _metrics.histogram("bullion.io.pread_seconds").observe(
-                time.perf_counter() - t0)
-        else:
-            data = h.pread(offset, size)
+        data = h.pread(offset, size)
         with self._stats_lock:
             self.stats.preads += 1
             self.stats.bytes_read += size

@@ -15,7 +15,6 @@ out in ``execute_group``, which orders the stages exactly once:
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Optional, Sequence
 
@@ -83,25 +82,25 @@ def _pad_raw(decoded, dv: Optional[np.ndarray], page_rows: int):
     return out
 
 
-# page-type flag -> histogram name, cached (per-family decode-time metric)
-_FAMILY_HIST: dict[int, str] = {}
+# page-type flag -> encoding family name, cached (``decode.decode`` spans)
+_FAMILY: dict[int, str] = {}
 
 
-def _decode_page_timed(flag: int, blob: bytes):
-    """Traced-mode decode: per-page wall time lands in the per-encoding-
-    family histogram (``bullion.decode.page_seconds.<family>``)."""
-    t0 = time.perf_counter()
-    decoded = pages_mod.decode_page(flag, blob)
-    dt = time.perf_counter() - t0
-    name = _FAMILY_HIST.get(flag)
-    if name is None:
-        try:
-            fam = PageType(flag).name.lower()
-        except ValueError:
-            fam = f"type{flag}"
-        name = _FAMILY_HIST[flag] = f"bullion.decode.page_seconds.{fam}"
-    _metrics.histogram(name).observe(dt)
-    return decoded
+def _encoding(flags: np.ndarray, pids: Sequence[int]) -> str:
+    """The encoding family of a chunk's pages (``PageType`` names, lower
+    case), or the families joined by ``+`` where the chunk mixes them."""
+    fams = set()
+    for p in pids:
+        flag = int(flags[p]) & 0x7F
+        name = _FAMILY.get(flag)
+        if name is None:
+            try:
+                name = PageType(flag).name.lower()
+            except ValueError:
+                name = f"type{flag}"
+            _FAMILY[flag] = name
+        fams.add(name)
+    return "+".join(sorted(fams))
 
 
 def _mask_fill(fv, col: int, rows: int):
@@ -132,8 +131,9 @@ def decode_group(reader: "BullionReader", names: Sequence[str], group: int, *,
     raw row space (only meaningful with ``drop_deleted=False``); the default
     keeps physical page content, which ``verify_deleted`` audits.
 
-    Each stage is a distinct span (``decode.pread`` / ``decode.decode`` /
-    ``decode.mask`` / ``decode.dequantize``) so traces and
+    Each stage is a distinct span (``decode.pread`` / ``decode.decode``,
+    which names the chunk's ``encoding`` / ``decode.mask`` /
+    ``decode.dequantize``) so traces and
     ``explain(analyze=True)`` attribute time per stage; with tracing
     disabled the spans are shared no-ops and the stage order is the only
     (behavior-identical) difference from an uninstrumented decode.
@@ -151,7 +151,6 @@ def decode_group(reader: "BullionReader", names: Sequence[str], group: int, *,
         raw = reader._read_pages(wanted)
         if sp.enabled:
             sp.set(bytes=sum(len(b) for b in raw.values()))
-    traced = _trace.enabled()
     out: dict = {}
 
     def _dec(c: int, p: int):
@@ -165,14 +164,15 @@ def decode_group(reader: "BullionReader", names: Sequence[str], group: int, *,
             if masked_out is not None:
                 masked_out.add(p)
             return _mask_fill(fv, c, int(page_rows[p]))
-        if traced:
-            return _decode_page_timed(int(flags[p]) & 0x7F, blob)
         return pages_mod.decode_page(int(flags[p]) & 0x7F, blob)
 
     for name, c in zip(names, cols):
         pids = _chunk_page_ids(fv, group, c, pages)
-        with _trace.span("decode.decode", cat="decode",
-                         column=name, pages=len(pids)):
+        sp = _trace.span("decode.decode", cat="decode", column=name,
+                         pages=len(pids))
+        if sp.enabled:
+            sp.set(encoding=_encoding(flags, pids))
+        with sp:
             parts = [_dec(c, p) for p in pids]
         if drop_deleted or align_raw:
             with _trace.span("decode.mask", cat="decode", column=name):
@@ -276,14 +276,25 @@ def eval_mask(pred: Predicate, tbl: dict,
         use_kernel = kernel_ok
     if not use_kernel:
         return evaluate(pred, tbl)
-    from ..kernels.filter import range_mask
-    names = list(ranges)
-    bounds = [_f32_shrink(*ranges[c]) for c in names]
-    cols = np.stack([np.asarray(tbl[c], np.float32) for c in names])
-    _metrics.counter("bullion.filter.kernel_calls").inc()
-    return range_mask(cols,
-                      np.asarray([b[0] for b in bounds], np.float32),
-                      np.asarray([b[1] for b in bounds], np.float32))
+    from ..kernels.filter import ops
+    # the device round trip, split: host staging and the puts, the jitted
+    # call's dispatch, and the wait for the mask and its copy back
+    with _trace.span("filter.stage", cat="filter"):
+        names = list(ranges)
+        bounds = [_f32_shrink(*ranges[c]) for c in names]
+        cols = np.stack([np.asarray(tbl[c], np.float32) for c in names])
+        _metrics.counter("bullion.filter.kernel_calls").inc()
+        staged = ops.stage(cols,
+                           np.asarray([b[0] for b in bounds], np.float32),
+                           np.asarray([b[1] for b in bounds], np.float32))
+    with _trace.span("filter.launch", cat="filter"):
+        out = ops.launch(staged)
+    with _trace.span("filter.fetch", cat="filter"):
+        # drop the inputs' device arrays before the wait for the mask;
+        # inside the span, so that launch and fetch abut
+        n_values = staged.n_values
+        del staged
+        return ops.fetch(out, n_values)
 
 
 # ---------------------------------------------------------------------------

@@ -44,4 +44,5 @@ def range_mask_pallas(cols: jax.Array, lo: jax.Array, hi: jax.Array,
         out_specs=pl.BlockSpec((1, BLOCK_N), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, N), jnp.uint8),
         interpret=interpret,
+        name="range_mask",
     )(cols, lo.reshape(C, 1), hi.reshape(C, 1))

@@ -9,11 +9,13 @@ included — may depend on it without cycles):
   process-wide slot. Disabled (the default) it is a no-op that allocates
   nothing on the hot path; ``collect()`` scopes a tracer to a block
   (forwarding to any enclosing recording), ``BULLION_TRACE=path`` records
-  process-wide and exports Chrome trace JSON at exit.
+  process-wide and exports Chrome trace JSON at exit. Once JAX is loaded,
+  each span is also a ``jax.profiler`` annotation, on the device trace's
+  clock.
 * ``metrics`` — a process-wide ``MetricsRegistry`` of named counters and
-  log-scale histograms (pread latency, coalesced-run sizes, queue depth,
-  per-encoding-family page decode time). Counters absorb ``IOStats`` when
-  reader accounting retires; timing histograms follow ``trace.enabled()``.
+  log-scale histograms (coalesced-run sizes, queue depths, remote fetch
+  and served query latency). Counters absorb ``IOStats`` when reader
+  accounting retires; the local read and decode stages are timed by spans.
 * ``export`` — Chrome ``trace_event`` rendering (``chrome_trace`` /
   ``write_trace``) viewable in Perfetto, plus the ``Profile`` object
   ``Dataset.profile()`` / ``ServeClient.profile()`` return.

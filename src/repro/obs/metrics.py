@@ -9,16 +9,13 @@ instrumented stack follows:
 * **counters are always cheap enough to leave on** — preads, bytes,
   cache hits absorbed in bulk from ``IOStats`` at reader-retire time
   (``absorb_iostats``), run sizes observed once per coalesced submission;
-* **timing histograms record only while tracing is enabled** — wrapping
-  every ``os.pread`` in two ``perf_counter`` calls is not free, so the
-  per-call latency distributions (``bullion.io.pread_seconds``, per-family
-  page decode time) follow ``trace.enabled()``; with tracing off the hot
-  path pays one global read.
+* **local stage times are spans, not histograms** — a pread or a page
+  decode is timed by the tracer's ``io.run`` and ``decode.decode`` spans,
+  recorded only while a tracer is installed; with tracing off the hot path
+  takes no clock reads for them.
 
-Names are dotted lowercase (``bullion.io.pread_seconds``); the per-family
-decode histograms append the ``PageType`` name
-(``bullion.decode.page_seconds.scalar``). ``snapshot()`` renders the whole
-registry as plain dicts for printing or shipping.
+Names are dotted lowercase (``bullion.io.run_bytes``). ``snapshot()``
+renders the whole registry as plain dicts for printing or shipping.
 """
 
 from __future__ import annotations

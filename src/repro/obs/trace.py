@@ -18,6 +18,11 @@ contract the hot paths rely on:
   *forwards* every finished span to the tracer it shadowed, so a scoped
   ``explain(analyze=True)`` or ``Dataset.profile()`` never hides events
   from a process-wide ``BULLION_TRACE`` recording.
+* **spans land on the profiler's clock** — while JAX is loaded, every real
+  span also opens a ``jax.profiler.TraceAnnotation`` of its name on entry
+  and closes it on exit (same thread), so a ``jax.profiler`` trace shows
+  the program's stages beside the device's ops. JAX is looked up in
+  ``sys.modules``, never imported: without it spans record as before.
 * **``BULLION_TRACE=path``** enables a process-wide tracer when
   ``repro.obs`` first loads and writes a Chrome ``trace_event`` JSON
   (loadable in Perfetto / chrome://tracing) at interpreter exit.
@@ -147,10 +152,24 @@ def allocations() -> int:
     return _allocations
 
 
-class Span:
-    """A live span: context manager recording wall time on exit."""
+# jax.profiler.TraceAnnotation once JAX is loaded; found lazily so that this
+# module never imports JAX itself
+_annotation = None
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+
+def _annotation_type():
+    global _annotation
+    if _annotation is None:
+        prof = sys.modules.get("jax.profiler")
+        _annotation = getattr(prof, "TraceAnnotation", None)
+    return _annotation
+
+
+class Span:
+    """A live span: context manager recording wall time on exit, and a
+    profiler annotation of its name around it while JAX is loaded."""
+
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
     enabled = True
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
@@ -162,6 +181,7 @@ class Span:
         self.cat = cat
         self.args = args
         self._t0 = 0.0
+        self._ann = None
 
     def set(self, **kw) -> "Span":
         """Attach attributes mid-span (guard expensive computation with
@@ -170,11 +190,18 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        ann = _annotation_type()
+        if ann is not None:
+            self._ann = ann(self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         th = threading.current_thread()
         self._tracer._record(SpanRecord(
             self.name, self.cat, self._t0 - _EPOCH, t1 - self._t0,

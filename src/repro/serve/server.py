@@ -296,7 +296,8 @@ class DatasetServer:
             depth = self._pending
         _metrics.histogram("bullion.serve.queue_depth").observe(depth)
         fut = self._pool.submit(self._run, dataset, columns, where, head,
-                                tenant, io_depth, trace_id, collect_spans)
+                                tenant, io_depth, trace_id, collect_spans,
+                                queued=time.perf_counter())
         fut.add_done_callback(self._done)
         return fut
 
@@ -329,7 +330,11 @@ class DatasetServer:
 
     def _run(self, dataset: str, columns, where, head, tenant: str,
              io_depth: Optional[int], trace_id: Optional[str] = None,
-             collect_spans: bool = False) -> QueryResult:
+             collect_spans: bool = False, *,
+             queued: Optional[float] = None) -> QueryResult:
+        """Run one query on a pool thread. ``wall_seconds`` starts here;
+        the wait for the thread since ``queued`` (the submit instant) is
+        the ``serve.query`` span's ``queued_ms``."""
         t0 = time.perf_counter()
         rec = _querylog.QueryRecord(
             ts=time.time(), origin="serve", dataset=dataset, tenant=tenant,
@@ -346,22 +351,29 @@ class DatasetServer:
         held = 0
         budget = None
         try:
-            ds, fp, hit = self.prepare(dataset, columns=columns, where=where,
-                                       head=head)
-            rec.fingerprint, rec.cache_hit = fp, hit
-            source = self._sources[dataset]
-            budget = self.tenant_budget(tenant)
-            want = self.default_io_depth if io_depth is None else io_depth
-            held = budget.acquire(want)
+            # the scope opens before planning, so a plan-cache miss's plan
+            # spans are the query's too; they close before ``serve.query``
+            # opens, on this thread
             if want_spans:
                 scope = _trace.collect()
                 tracer = scope.__enter__()
             try:
+                ds, fp, hit = self.prepare(dataset, columns=columns,
+                                           where=where, head=head)
+                rec.fingerprint, rec.cache_hit = fp, hit
+                source = self._sources[dataset]
+                budget = self.tenant_budget(tenant)
+                want = self.default_io_depth if io_depth is None \
+                    else io_depth
+                held = budget.acquire(want)
                 before = source.stats
                 sp = _trace.span("serve.query", cat="serve", dataset=dataset,
                                  tenant=tenant, cache_hit=hit)
-                if trace_id is not None and sp.enabled:
-                    sp.set(trace_id=trace_id)
+                if sp.enabled:
+                    if queued is not None:
+                        sp.set(queued_ms=1e3 * (t0 - queued))
+                    if trace_id is not None:
+                        sp.set(trace_id=trace_id)
                 with sp:
                     table = ds.to_table(io_depth=held)
                 # exact for this query while queries on the dataset don't
@@ -510,18 +522,55 @@ class DatasetServer:
                 try:
                     resp = self._dispatch(req)
                 except Exception as e:   # per-request fault isolation
-                    if not getattr(e, "__bullion_logged__", False):
-                        self._wire_error(f"{type(e).__name__}: {e}",
-                                         op=req.get("op"),
-                                         dataset=req.get("dataset"))
-                    resp = {"ok": False,
-                            "error": f"{type(e).__name__}: {e}"}
+                    resp = self._failed(req, e)
                 try:
-                    wire.send_msg(conn, resp)
+                    if isinstance(resp, QueryResult):
+                        # timed from the moment the result is in hand; the
+                        # send blocks until the client has read all but a
+                        # socket buffer, so ``encode_ms`` is the server's own
+                        # part. Rebinding ``resp`` drops the decoded table
+                        # once it is encoded.
+                        sp = _trace.span("serve.respond", cat="serve",
+                                         tenant=resp.tenant)
+                        with sp:
+                            t0 = time.perf_counter()
+                            resp = wire.frame(self._answer(req, resp))
+                            if sp.enabled:
+                                sp.set(encode_ms=1e3 * (
+                                    time.perf_counter() - t0))
+                            conn.sendall(resp)
+                    else:
+                        wire.send_msg(conn, resp)
                 except OSError:
                     return
 
-    def _dispatch(self, req: dict) -> dict:
+    def _failed(self, req: dict, e: Exception) -> dict:
+        """The answer to a request that raised, logged unless the query
+        path already logged it."""
+        if not getattr(e, "__bullion_logged__", False):
+            self._wire_error(f"{type(e).__name__}: {e}", op=req.get("op"),
+                             dataset=req.get("dataset"))
+        return {"ok": False, "error": f"{type(e).__name__}: {e}"}
+
+    def _answer(self, req: dict, res: QueryResult) -> dict:
+        """The wire answer to a served query: its table encoded."""
+        try:
+            resp = {"ok": True, "rows": res.rows,
+                    "cache_hit": res.cache_hit,
+                    "fingerprint": res.fingerprint,
+                    "wall_seconds": res.wall_seconds,
+                    "degraded": res.degraded,
+                    "degraded_rows": res.degraded_rows,
+                    "table": wire.encode_table(res.table)}
+        except Exception as e:
+            return self._failed(req, e)
+        if req.get("trace"):
+            resp["trace"] = {"id": res.trace_id, "spans": res.spans or []}
+        return resp
+
+    def _dispatch(self, req: dict):
+        """The answer to one request: a dict, or for a query its
+        ``QueryResult``, which ``_answer`` encodes."""
         op = req.get("op")
         if op == "ping":
             return {"ok": True, "pong": True}
@@ -542,25 +591,13 @@ class DatasetServer:
                 head=req.get("head"))}
         if op == "query":
             trace_req = req.get("trace") or {}
-            trace_id = trace_req.get("id")
-            res = self.query(
+            return self.query(
                 req["dataset"], columns=req.get("columns"),
                 where=wire.decode_predicate(req.get("where")),
                 head=req.get("head"),
                 tenant=req.get("tenant", DEFAULT_TENANT),
                 io_depth=req.get("io_depth"),
-                trace_id=trace_id, collect_spans=bool(trace_req))
-            resp = {"ok": True, "rows": res.rows,
-                    "cache_hit": res.cache_hit,
-                    "fingerprint": res.fingerprint,
-                    "wall_seconds": res.wall_seconds,
-                    "degraded": res.degraded,
-                    "degraded_rows": res.degraded_rows,
-                    "table": wire.encode_table(res.table)}
-            if trace_req:
-                resp["trace"] = {"id": trace_id,
-                                 "spans": res.spans or []}
-            return resp
+                trace_id=trace_req.get("id"), collect_spans=bool(trace_req))
         self._wire_error(f"unknown op {op!r}", op=op,
                          dataset=req.get("dataset"))
         return {"ok": False, "error": f"unknown op {op!r}"}

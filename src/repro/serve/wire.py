@@ -31,13 +31,18 @@ MAX_MESSAGE = 1 << 30
 # framing
 # ---------------------------------------------------------------------------
 
-def send_msg(sock: socket.socket, obj: dict) -> None:
+def frame(obj: dict) -> bytes:
+    """One message as it goes on the socket: length prefix and JSON."""
     data = json.dumps(obj).encode()
     if len(data) > MAX_MESSAGE:
         # refuse to emit a frame the peer is contractually bound to reject
         # (and that would wrap the u32 length prefix past 4 GiB)
         raise ValueError(f"frame of {len(data)} bytes exceeds {MAX_MESSAGE}")
-    sock.sendall(_LEN.pack(len(data)) + data)
+    return _LEN.pack(len(data)) + data
+
+
+def send_msg(sock: socket.socket, obj: dict) -> None:
+    sock.sendall(frame(obj))
 
 
 def _recv_exact(sock: socket.socket, n: int) -> Optional[bytes]:
