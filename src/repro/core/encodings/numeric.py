@@ -37,13 +37,51 @@ def pack_bits(vals: np.ndarray, width: int) -> bytes:
     return np.packbits(bits.reshape(-1), bitorder="little").tobytes()
 
 
+_OFFSETS: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+
+
+def _bit_offsets(n: int, width: int) -> tuple[np.ndarray, np.ndarray]:
+    """Byte offset and bit shift of each of the first `n` packed values.
+
+    One read-only pair per width, grown to the largest `n` asked for and
+    sliced, so the cache holds 16 bytes a value of the longest page at each
+    width in use, whatever the mix of page lengths.
+    """
+    offsets = _OFFSETS.get(width)
+    if offsets is None or len(offsets[0]) < n:
+        bit = np.arange(n, dtype=np.int64) * width
+        offsets = bit >> 3, (bit & 7).astype(np.uint64)
+        offsets[0].flags.writeable = offsets[1].flags.writeable = False
+        _OFFSETS[width] = offsets
+    return offsets[0][:n], offsets[1][:n]
+
+
 def unpack_bits(buf: memoryview | bytes, n: int, width: int) -> np.ndarray:
+    """Inverse of `pack_bits`: `n` values of `width` (0..64) bits as uint64.
+
+    Value i starts at bit i*width: one unaligned little-endian 64-bit load
+    at its byte, one shift and a mask. Where a value straddles nine bytes
+    (widths over 56) its high bits come from the byte after the load. The
+    work is a handful of whole-array NumPy calls, each of which drops the
+    GIL, so threads decoding pages side by side trade it a few times a page.
+    """
     if width == 0 or n == 0:
         return np.zeros(n, np.uint64)
-    raw = np.frombuffer(buf, np.uint8, count=(n * width + 7) // 8)
-    bits = np.unpackbits(raw, count=n * width, bitorder="little").reshape(n, width)
-    shifts = np.arange(width, dtype=np.uint64)
-    return (bits.astype(np.uint64) << shifts).sum(axis=1, dtype=np.uint64)
+    nbytes = (n * width + 7) // 8
+    # a tail keeps the last loads in bounds; what it holds lands only past
+    # a value's width, so it never reaches the result
+    padded = np.empty(nbytes + 9, np.uint8)
+    padded[:nbytes] = np.frombuffer(buf, np.uint8, count=nbytes)
+    byte, shift = _bit_offsets(n, width)
+    words = np.ndarray((nbytes + 1,), "<u8", padded, 0, (1,))
+    out = words.take(byte)
+    out >>= shift
+    if width > 56:
+        high = padded.take(byte + 8).astype(np.uint64)
+        high <<= np.uint64(64) - shift  # a shift by 64 gives 0
+        out |= high
+    out &= np.uint64((1 << width) - 1)
+    return out
 
 
 def bit_width(max_val: int) -> int:
@@ -174,7 +212,7 @@ class FixedBitWidth(Encoding):
 
     def decode(self, header, payload):
         code, n, width = struct.unpack_from("<BQB", header)
-        return unpack_bits(payload, n, width).astype(code_dtype(code))
+        return unpack_bits(payload, n, width).astype(code_dtype(code), copy=False)
 
     def mask(self, header, payload, positions, n_values):
         code, n, width = struct.unpack_from("<BQB", header)
@@ -290,14 +328,15 @@ class Dictionary(Encoding):
         code, n, nuniq, width = struct.unpack_from("<BQQB", header)
         vblob, packed = _split2(payload)
         values = decode_blob(vblob)
-        codes = unpack_bits(packed, n, width).astype(np.int64)
+        codes = unpack_bits(packed, n, width).view(np.int64)
         # mask entries decode to a neutral 0 (NOT values[0] — decoding a real
         # value would make erasure audits see phantom occurrences); the page
         # DV drops these rows anyway
         masked = codes >= nuniq
-        out = values[np.where(masked, 0, codes)]
+        codes[masked] = 0
+        out = values[codes]
         out[masked] = 0
-        return out.astype(code_dtype(code))
+        return out.astype(code_dtype(code), copy=False)
 
     def mask(self, header, payload, positions, n_values):
         code, n, nuniq, width = struct.unpack_from("<BQQB", header)
@@ -324,7 +363,11 @@ class FOR(Encoding):
 
     def decode(self, header, payload):
         code, n, lo, width = struct.unpack_from("<BQqB", header)
-        return (unpack_bits(payload, n, width).astype(np.int64) + lo).astype(code_dtype(code))
+        # the uint64 sum wraps exactly as the int64 one would, and the
+        # store's cast truncates it to the code dtype in the same pass
+        out = np.empty(n, code_dtype(code))
+        return np.add(unpack_bits(payload, n, width), np.uint64(lo % (1 << 64)),
+                      out=out, casting="unsafe")
 
     def mask(self, header, payload, positions, n_values):
         code, n, lo, width = struct.unpack_from("<BQqB", header)
