@@ -45,7 +45,9 @@ Layout:
   source.py    — shard discovery (file/dir/glob/list), open-time schema
                  checking (``SchemaMismatchError``), reader lifecycle,
                  global row offsets, aggregate ``IOStats``
-  executor.py  — ``decode_group``/``execute_group``: the one read pipeline,
+  executor.py  — ``decode_group``/``execute_group``: the one read pipeline
+                 (``aggregate_group``: the same, ending in a partial
+                 aggregate),
                  plus ``run_tasks`` (bounded thread pool, deterministic order)
                  shared by parallel reads and the sink
   io.py        — ``IOScheduler``/``PrefetchReader``: plan-wide byte-range
@@ -58,21 +60,24 @@ Layout:
   core.py      — the chainable ``Dataset`` and the ``dataset()`` entry point
 """
 
-from .core import Dataset, DatasetBatch, dataset
-from .executor import GroupResult, decode_group, execute_group, run_tasks
+from .core import AggregateResult, Dataset, DatasetBatch, dataset
+from .executor import (GroupResult, aggregate_group, decode_group,
+                       execute_group, run_tasks)
 from .io import IOScheduler, PrefetchReader
-from .plan import (LogicalPlan, OptimizedPlan, PhysicalPlan, ScanTask, lower,
-                   optimize, split_conjuncts)
+from .plan import (LogicalPlan, OptimizedPlan, PhysicalPlan, ScanTask,
+                   SumProduct, lower, optimize, split_conjuncts)
 from .sink import WriteResult, write_dataset
 from .source import (DataSource, SchemaMismatchError, cached_footer,
                      clear_footer_cache, discover, invalidate_cached_footer)
 
 __all__ = [
-    "Dataset", "DatasetBatch", "dataset", "DataSource",
+    "AggregateResult", "Dataset", "DatasetBatch", "dataset", "DataSource",
     "SchemaMismatchError", "discover",
-    "GroupResult", "decode_group", "execute_group", "run_tasks",
+    "GroupResult", "aggregate_group", "decode_group", "execute_group",
+    "run_tasks",
     "IOScheduler", "PrefetchReader",
-    "LogicalPlan", "OptimizedPlan", "PhysicalPlan", "ScanTask", "lower",
+    "LogicalPlan", "OptimizedPlan", "PhysicalPlan", "ScanTask", "SumProduct",
+    "lower",
     "optimize", "split_conjuncts", "WriteResult", "write_dataset",
     "cached_footer", "clear_footer_cache", "invalidate_cached_footer",
 ]

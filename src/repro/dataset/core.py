@@ -3,9 +3,9 @@
 ``dataset(path_or_glob)`` opens one file, a directory of shards, a glob, or
 an explicit path list. Chaining (`select`/`where`/`with_rows`/`head`/...)
 only rewrites an immutable ``LogicalPlan``; no I/O happens until a terminal
-(``to_table``/``to_batches``/``count_rows``/``row_ids``) optimizes, lowers,
-and executes it. The same plan runs unchanged over single- and multi-file
-datasets.
+(``to_table``/``to_batches``/``count_rows``/``row_ids``/``aggregate``)
+optimizes, lowers, and executes it. The same plan runs unchanged over
+single- and multi-file datasets.
 """
 
 from __future__ import annotations
@@ -13,7 +13,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from dataclasses import dataclass, field
-from typing import Iterator, Optional, Sequence
+from typing import Iterator, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -24,7 +24,7 @@ from ..obs import trace as _trace
 from ..scan.predicate import Predicate
 from . import executor
 from .plan import LogicalPlan, OptimizedPlan, PhysicalPlan, ScanTask, \
-    group_bounds as _group_bounds, lower, optimize
+    SumProduct, group_bounds as _group_bounds, lower, optimize
 from .source import DataSource, PathSpec
 
 
@@ -48,6 +48,13 @@ class DatasetBatch:
     group: int
     row_ids: np.ndarray              # global ids, raw row space
     table: dict = field(default_factory=dict)
+
+
+class AggregateResult(NamedTuple):
+    """An aggregate's answer: the exact value and the rows it covered."""
+
+    value: int
+    rows: int
 
 
 class Dataset:
@@ -227,6 +234,7 @@ class Dataset:
             if p.row_ids is not None else "  rows: -",
             f"  dequantize: {p.dequantize}  drop_deleted: {p.drop_deleted}"
             f"  limit: {p.limit}",
+            f"  aggregate: {p.aggregate or '-'}",
             f"  read columns (narrowed): {list(opt.read_columns)}",
             f"PhysicalPlan: {self.n_shards} shard(s), {len(phys.tasks)} task(s)",
             f"  groups: {phys.groups_total - phys.groups_pruned}/"
@@ -493,6 +501,54 @@ class Dataset:
                    for _, res in self._execute(output_columns=(),
                                                parallelism=parallelism,
                                                io_depth=io_depth))
+
+    def aggregate(self, *, sum_product: Sequence[str], parallelism: int = 1,
+                  io_depth: int = 1) -> AggregateResult:
+        """``sum(a * b)`` over the plan's rows, with ``sum_product=(a, b)``
+        two integer columns, and the number of rows: an exact integer,
+        however large. Only the predicate and factor columns are read.
+        Each row group's partial aggregate is computed where it is decoded
+        (the fused Pallas filter-and-sum kernel where the predicate is a
+        conjunction of ranges over int32 columns and the group's zone maps
+        keep the kernel exact, NumPy otherwise) and the partials are added
+        in Python ints; no group's rows are materialized."""
+        agg = SumProduct(*sum_product)
+        ds = self if self._plan.aggregate == agg \
+            else self._chain(aggregate=agg)
+        return ds._aggregate(parallelism, io_depth)
+
+    def _aggregate(self, parallelism: int, io_depth: int) -> AggregateResult:
+        opt = self.plan()
+        phys = self.physical_plan()
+        self._credit(phys)
+        p = opt.logical
+        if io_depth < 1:
+            raise ValueError(f"io_depth must be >= 1, got {io_depth}")
+        sched = None
+        if io_depth > 1 and len(phys.tasks) > 1:
+            from .io import IOScheduler
+            sched = IOScheduler(self._source, phys.tasks,
+                                columns=opt.prefetch_columns(),
+                                io_depth=io_depth)
+
+        def run(item) -> tuple[int, int]:
+            i, task = item
+            with _trace.span("exec.task", cat="exec",
+                             shard=task.shard, group=task.group):
+                reader = sched.reader_for(i) if sched is not None \
+                    else self._source.reader(task.shard)
+                return executor.aggregate_group(
+                    reader, task.group, factors=p.aggregate.columns(),
+                    predicate=p.predicate, rows=task.rows,
+                    drop_deleted=p.drop_deleted, use_kernel=p.use_kernel,
+                    pages=task.pages)
+
+        value = rows = 0
+        for _, (v, n) in executor.run_tasks(
+                list(enumerate(phys.tasks)), run, parallelism, io=sched):
+            value += v
+            rows += n
+        return AggregateResult(value, rows)
 
     def profile(self, path: Optional[str] = None, *,
                 parallelism: int = 1, io_depth: int = 1):

@@ -11,12 +11,17 @@ out in ``execute_group``, which orders the stages exactly once:
 ``decode_group`` is the pread+decode+mask+dequantize core (moved here from
 ``BullionReader.project``); ``execute_group`` layers predicate evaluation
 (NumPy or the Pallas batch filter kernel) and raw-row-id selection on top.
+``aggregate_group`` is the same pipeline ending in a partial aggregate in
+place of the gather: the group's sum of products and matching-row count,
+from the fused Pallas filter-and-sum kernel or from NumPy, exact either way.
 """
 
 from __future__ import annotations
 
+import functools
+import math
 from dataclasses import dataclass, field
-from typing import TYPE_CHECKING, Optional, Sequence
+from typing import TYPE_CHECKING, Callable, Optional, Sequence
 
 import numpy as np
 
@@ -298,6 +303,112 @@ def eval_mask(pred: Predicate, tbl: dict,
 
 
 # ---------------------------------------------------------------------------
+# partial aggregates (NumPy or the fused Pallas filter-and-sum kernel)
+# ---------------------------------------------------------------------------
+
+_INT32 = (-(1 << 31), (1 << 31) - 1)
+
+
+def _int32_safe(x) -> bool:
+    """Does every value of this decoded column's dtype fit in int32?"""
+    dt = getattr(x, "dtype", None)
+    return dt is not None and (dt.kind == "i" and dt.itemsize <= 4
+                               or dt.kind == "u" and dt.itemsize <= 2)
+
+
+def factor_bounds(fv, group: int, factors: Sequence[str]
+                  ) -> Optional[tuple[int, ...]]:
+    """Largest magnitude of each factor column in the group, from its chunk
+    zone map (outer bounds); None where a chunk has no min/max."""
+    from ..scan.stats import HAS_MINMAX
+    chunk = fv.chunk_stats()
+    if chunk is None:
+        return None
+    out = []
+    for name in factors:
+        rec = chunk[group * fv.n_cols + fv.column_index(name)]
+        if not int(rec["flags"]) & HAS_MINMAX:
+            return None
+        out.append(math.ceil(max(abs(float(rec["min"])),
+                                 abs(float(rec["max"])))))
+    return tuple(out)
+
+
+def _host_sum_product(x: np.ndarray, y: np.ndarray) -> int:
+    """Exact ``sum(x * y)`` of two integer arrays, as a Python int."""
+    if max(x.dtype.itemsize, y.dtype.itemsize) > 4 \
+            or x.dtype == y.dtype == np.uint32:
+        # a product may pass int64: multiply Python ints
+        return sum((x.astype(object) * y.astype(object)).tolist())
+    p = x.astype(np.int64) * y.astype(np.int64)     # |p| < 2**63
+    # 32-bit halves: neither half's sum can wrap below 2**31 rows
+    return (int((p >> 32).sum()) << 32) + int((p & 0xFFFFFFFF).sum())
+
+
+def eval_sum_product(pred: Optional[Predicate], tbl: dict,
+                     factors: Sequence[str],
+                     bounds: Optional[Sequence[int]],
+                     use_kernel: Optional[bool],
+                     rows_mask: Optional[np.ndarray] = None
+                     ) -> tuple[int, int]:
+    """(sum of ``factors[0] * factors[1]`` over the rows of ``tbl`` that
+    pass ``pred`` and ``rows_mask``, their count), exact. The fused kernel
+    runs when the predicate is a conjunction of ranges, every column it
+    reads is an integer column that fits int32, and the factors'
+    magnitudes ``bounds`` keep its lane sums exact; NumPy otherwise."""
+    from ..kernels.aggregate import ops as kernel_ops
+    a, b = factors
+    n = len(tbl[a])
+    cols = pred.columns() if pred is not None else set()
+    ranges = conjunctive_ranges(
+        pred, int_columns={c for c in cols if _int32_safe(tbl[c])}) \
+        if pred is not None else {}
+    order = None
+    if bounds is not None:
+        if kernel_ops.exact_for(n, bounds[0], bounds[1]):
+            order = (a, b)
+        elif kernel_ops.exact_for(n, bounds[1], bounds[0]):
+            order = (b, a)
+    kernel_ok = (ranges is not None and rows_mask is None
+                 and order is not None
+                 and all(_int32_safe(tbl[c]) for c in {*cols, a, b}))
+    if use_kernel and not kernel_ok:
+        raise ValueError(
+            "the aggregate kernel requires a conjunctive range predicate "
+            "over int32 columns, no pinned rows, and factors whose zone "
+            "maps keep its int32 lane sums exact")
+    if use_kernel is None:
+        use_kernel = kernel_ok
+    if not use_kernel:
+        _metrics.counter("bullion.aggregate.host_groups").inc()
+        mask = evaluate(pred, tbl) if pred is not None \
+            else np.ones(n, bool)
+        if rows_mask is not None:
+            mask &= rows_mask
+        return (_host_sum_product(np.asarray(tbl[a])[mask],
+                                  np.asarray(tbl[b])[mask]),
+                int(mask.sum()))
+    # an interval outside int32 admits no int32 value
+    if any(max(lo, _INT32[0]) > min(hi, _INT32[1])
+           for lo, hi in ranges.values()):
+        return 0, 0
+    with _trace.span("aggregate.stage", cat="aggregate"):
+        names = list(dict.fromkeys([*ranges, *order]))
+        full = (_INT32[0], _INT32[1])
+        lo = [max(ranges.get(c, full)[0], _INT32[0]) for c in names]
+        hi = [min(ranges.get(c, full)[1], _INT32[1]) for c in names]
+        _metrics.counter("bullion.aggregate.kernel_calls").inc()
+        staged = kernel_ops.stage(
+            np.stack([np.asarray(tbl[c], np.int32) for c in names]), lo, hi,
+            names.index(order[0]), names.index(order[1]))
+    with _trace.span("aggregate.launch", cat="aggregate"):
+        out = kernel_ops.launch(staged)
+    with _trace.span("aggregate.fetch", cat="aggregate"):
+        del staged
+        return kernel_ops.fetch(out)
+
+
+# ---------------------------------------------------------------------------
 # the one per-group pipeline
 # ---------------------------------------------------------------------------
 
@@ -321,26 +432,34 @@ def execute_group(reader: "BullionReader", group: int, *,
                   use_kernel: Optional[bool] = None,
                   pages: Optional[Sequence[int]] = None
                   ) -> Optional[GroupResult]:
-    """Decode + filter one row group with graceful degradation.
+    """Decode + filter one row group with graceful degradation
+    (``_under_policy``)."""
+    return _under_policy(reader, group, pages, functools.partial(
+        _execute_group_once, reader, group, columns=columns,
+        predicate=predicate, rows=rows, drop_deleted=drop_deleted,
+        dequant=dequant, use_kernel=use_kernel))
 
-    The inner pipeline (``_execute_group_once``) raises
-    ``ShardCorruptError`` when decode-time verification quarantines a page.
-    Under the ``skip`` corruption policy that page's *ordinal* is excluded
-    (dropping the same row range from every column — the result stays
-    rectangular) and the group retries; dropped rows are charged exactly
-    once to ``IOStats.degraded_rows``. Under ``mask`` the verification gate
-    already zero-filled the page; the masked rows are charged here. Under
-    ``raise`` (the default) the error propagates with (shard, group, page).
+
+def _under_policy(reader: "BullionReader", group: int,
+                  pages: Optional[Sequence[int]], once: Callable):
+    """Run ``once(pages=..., masked_out=...)``, one pass over the group,
+    under the corruption policy.
+
+    The pass raises ``ShardCorruptError`` when decode-time verification
+    quarantines a page. Under the ``skip`` corruption policy that page's
+    *ordinal* is excluded (dropping the same row range from every column —
+    the result stays rectangular) and the group retries; dropped rows are
+    charged exactly once to ``IOStats.degraded_rows``. Under ``mask`` the
+    verification gate already zero-filled the page; the masked rows are
+    charged here. Under ``raise`` (the default) the error propagates with
+    (shard, group, page).
     """
     fv = reader.footer
     policy = _integrity.corruption_policy()
     masked_out: Optional[set] = set() \
         if policy == _integrity.ON_CORRUPT_MASK else None
     if policy != _integrity.ON_CORRUPT_SKIP:
-        res = _execute_group_once(
-            reader, group, columns=columns, predicate=predicate, rows=rows,
-            drop_deleted=drop_deleted, dequant=dequant, use_kernel=use_kernel,
-            pages=pages, masked_out=masked_out)
+        res = once(pages=pages, masked_out=masked_out)
         if masked_out:
             page_rows = fv.arr(Sec.PAGE_ROWS, np.uint32)
             _charge_degraded(
@@ -363,10 +482,7 @@ def execute_group(reader: "BullionReader", group: int, *,
         else:
             eff = pages
         try:
-            res = _execute_group_once(
-                reader, group, columns=columns, predicate=predicate,
-                rows=rows, drop_deleted=drop_deleted, dequant=dequant,
-                use_kernel=use_kernel, pages=eff)
+            res = once(pages=eff, masked_out=None)
         except ShardCorruptError as e:
             if e.page is None or e.path != reader.path:
                 raise
@@ -390,6 +506,33 @@ def _charge_degraded(reader: "BullionReader", n_rows: int) -> None:
     with reader._stats_lock:
         reader.stats.degraded_rows += n_rows
     _metrics.counter("bullion.integrity.degraded_rows").inc(n_rows)
+
+
+def _row_space(fv, group: int, pages: Optional[Sequence[int]],
+               drop_deleted: bool) -> tuple[Optional[np.ndarray], int]:
+    """The raw rows a decode of the group's selected pages yields, in order
+    (None = all of the group's rows), and how many there are."""
+    sel_raw = selected_raw_rows(fv, group, pages)
+    keep = group_keep(fv, group, pages=pages) if drop_deleted else None
+    if keep is not None:
+        space_raw = sel_raw[keep] if sel_raw is not None \
+            else np.flatnonzero(keep)
+    else:
+        space_raw = sel_raw
+    n_space = len(space_raw) if space_raw is not None \
+        else raw_row_count(fv, group)
+    return space_raw, n_space
+
+
+def _rows_mask(rows: np.ndarray, space_raw: Optional[np.ndarray],
+               n_space: int) -> np.ndarray:
+    """Mask over the decoded rows of the pinned group-local raw ``rows``."""
+    rmask = np.zeros(n_space, bool)
+    if space_raw is None:
+        rmask[rows[rows < n_space]] = True
+    else:
+        rmask[np.isin(space_raw, rows)] = True
+    return rmask
 
 
 def _execute_group_once(reader: "BullionReader", group: int, *,
@@ -417,15 +560,7 @@ def _execute_group_once(reader: "BullionReader", group: int, *,
     fv = reader.footer
     if pages is not None and not len(pages):
         return None
-    sel_raw = selected_raw_rows(fv, group, pages)
-    keep = group_keep(fv, group, pages=pages) if drop_deleted else None
-    if keep is not None:
-        space_raw = sel_raw[keep] if sel_raw is not None \
-            else np.flatnonzero(keep)
-    else:
-        space_raw = sel_raw
-    n_space = len(space_raw) if space_raw is not None \
-        else raw_row_count(fv, group)
+    space_raw, n_space = _row_space(fv, group, pages, drop_deleted)
 
     pred_cols = sorted(predicate.columns()) if predicate is not None else []
     reuse = set(pred_cols) if dequant else set()
@@ -444,11 +579,7 @@ def _execute_group_once(reader: "BullionReader", group: int, *,
             if sp.enabled:
                 sp.set(rows_in=int(len(mask)), rows_out=int(mask.sum()))
     if rows is not None:
-        rmask = np.zeros(n_space, bool)
-        if space_raw is None:
-            rmask[rows[rows < n_space]] = True
-        else:
-            rmask[np.isin(space_raw, rows)] = True
+        rmask = _rows_mask(rows, space_raw, n_space)
         mask = rmask if mask is None else mask & rmask
 
     if mask is None:
@@ -477,6 +608,53 @@ def _execute_group_once(reader: "BullionReader", group: int, *,
         for name in rest:
             out[name] = ptbl[name] if full else _take(ptbl[name], local)
     return GroupResult(row_ids=raw_local, table=out)
+
+
+def aggregate_group(reader: "BullionReader", group: int, *,
+                    factors: Sequence[str],
+                    predicate: Optional[Predicate] = None,
+                    rows: Optional[np.ndarray] = None,
+                    drop_deleted: bool = True,
+                    use_kernel: Optional[bool] = None,
+                    pages: Optional[Sequence[int]] = None
+                    ) -> tuple[int, int]:
+    """One row group's partial aggregate: (sum of the factors' products
+    over its rows that pass ``predicate`` and the pinned ``rows``, their
+    count), under the corruption policy as ``execute_group``. The group's
+    rows never leave the executor."""
+    return _under_policy(reader, group, pages, functools.partial(
+        _aggregate_group_once, reader, group, factors=factors,
+        predicate=predicate, rows=rows, drop_deleted=drop_deleted,
+        use_kernel=use_kernel))
+
+
+def _aggregate_group_once(reader: "BullionReader", group: int, *,
+                          factors: Sequence[str],
+                          predicate: Optional[Predicate],
+                          rows: Optional[np.ndarray], drop_deleted: bool,
+                          use_kernel: Optional[bool],
+                          pages: Optional[Sequence[int]],
+                          masked_out: Optional[set]) -> tuple[int, int]:
+    fv = reader.footer
+    if pages is not None and not len(pages):
+        return 0, 0
+    pred_cols = sorted(predicate.columns()) if predicate is not None else []
+    tbl = decode_group(reader, list(dict.fromkeys([*pred_cols, *factors])),
+                       group, drop_deleted=drop_deleted, dequant=True,
+                       pages=pages, align_raw=not drop_deleted,
+                       masked_out=masked_out)
+    rows_mask = None
+    if rows is not None:
+        rows_mask = _rows_mask(rows,
+                               *_row_space(fv, group, pages, drop_deleted))
+    sp = _trace.span("exec.aggregate", cat="exec", group=group)
+    with sp:
+        value, count = eval_sum_product(
+            predicate, tbl, factors, factor_bounds(fv, group, factors),
+            use_kernel, rows_mask)
+        if sp.enabled:
+            sp.set(rows_in=len(tbl[factors[0]]), rows_out=count)
+    return value, count
 
 
 # ---------------------------------------------------------------------------

@@ -2,13 +2,14 @@
 
 A chained ``Dataset`` records *what* the caller wants in a ``LogicalPlan``
 (pure data, no I/O). ``optimize`` normalizes it — conjunct splitting,
-projection narrowing to predicate+output columns, validation against the
-dataset schema. ``lower`` turns the optimized plan into a ``PhysicalPlan``:
-one ``ScanTask`` per (shard, row group) that could contain a matching row —
-carrying the group's surviving page ordinals when page-granular zone maps
-pruned inside it — with every avoided group *and page* accounted as pruned
-bytes (zone maps, row-id location, or a ``head`` limit each prove reads
-unnecessary before any data pread).
+projection narrowing to predicate+output columns (predicate+factor columns
+for an aggregate), validation against the dataset schema. ``lower`` turns
+the optimized plan into a ``PhysicalPlan``: one ``ScanTask`` per (shard,
+row group) that could contain a matching row — carrying the group's
+surviving page ordinals when page-granular zone maps pruned inside it —
+with every avoided group *and page* accounted as pruned bytes (zone maps,
+row-id location, or a ``head`` limit each prove reads unnecessary before
+any data pread).
 """
 
 from __future__ import annotations
@@ -26,6 +27,21 @@ if TYPE_CHECKING:  # pragma: no cover - import cycle guard
 
 
 @dataclass(frozen=True)
+class SumProduct:
+    """Aggregate node: ``sum(a * b)`` over the plan's rows, and their count.
+    Both factors are integer columns, so the answer is an exact integer."""
+
+    a: str
+    b: str
+
+    def columns(self) -> tuple[str, str]:
+        return (self.a, self.b)
+
+    def __str__(self) -> str:
+        return f"sum_product({self.a}, {self.b})"
+
+
+@dataclass(frozen=True)
 class LogicalPlan:
     """Declarative description of one scan. Immutable; chaining replaces."""
 
@@ -37,6 +53,7 @@ class LogicalPlan:
     drop_deleted: bool = True
     limit: Optional[int] = None                 # head(n)
     use_kernel: Optional[bool] = None           # Pallas filter: None = auto
+    aggregate: Optional[SumProduct] = None      # None = return rows
 
     def replace(self, **kw) -> "LogicalPlan":
         return replace(self, **kw)
@@ -64,6 +81,7 @@ class LogicalPlan:
             f"limit={self.limit}",
             f"kernel={self.use_kernel}",
             f"rows={self.row_ids is not None}",
+            "agg=" + ("-" if self.aggregate is None else str(self.aggregate)),
         ]
         h = hashlib.sha256("\n".join(bits).encode())
         if self.row_ids is not None:
@@ -79,7 +97,8 @@ class OptimizedPlan:
     logical: LogicalPlan
     output_columns: tuple[str, ...]   # materialized in results, in order
     pred_columns: tuple[str, ...]     # referenced by the predicate
-    read_columns: tuple[str, ...]     # projection narrowing: output ∪ predicate
+    read_columns: tuple[str, ...]     # projection narrowing: output ∪
+                                      # predicate (∪ an aggregate's factors)
     conjuncts: tuple[Predicate, ...]  # top-level AND split (empty = no pred)
 
     def prefetch_columns(self, output_columns: Optional[Sequence[str]] = None
@@ -88,7 +107,10 @@ class OptimizedPlan:
         task. With a predicate, only the predicate columns are uncondi-
         tionally read — payload pages are fetched on demand so groups the
         filter empties still skip them (the serial path's second I/O win).
-        Without one, every read column's pages are certain to be decoded."""
+        Without one, every read column's pages are certain to be decoded,
+        as are an aggregate's, which decodes every read column at once."""
+        if self.logical.aggregate is not None:
+            return self.read_columns
         if self.logical.predicate is not None:
             return self.pred_columns
         return self.output_columns if output_columns is None \
@@ -158,7 +180,10 @@ def optimize(plan: LogicalPlan, source: "DataSource") -> OptimizedPlan:
 
 def _optimize(plan: LogicalPlan, source: "DataSource") -> OptimizedPlan:
     names = source.column_names
-    if plan.columns is None:
+    if plan.aggregate is not None:
+        output = ()
+        _check_factors(plan, source)
+    elif plan.columns is None:
         output = tuple(names)
     else:
         output = tuple(dict.fromkeys(plan.columns))
@@ -177,10 +202,34 @@ def _optimize(plan: LogicalPlan, source: "DataSource") -> OptimizedPlan:
         raise ValueError("groups= restriction is single-shard only; "
                          "use with_rows on multi-file datasets")
     # projection narrowing: the executor touches exactly these columns
-    read = tuple(dict.fromkeys([*output, *pred_cols]))
+    factors = plan.aggregate.columns() if plan.aggregate is not None else ()
+    read = tuple(dict.fromkeys([*output, *pred_cols, *factors]))
     return OptimizedPlan(logical=plan, output_columns=output,
                          pred_columns=pred_cols, read_columns=read,
                          conjuncts=conjuncts)
+
+
+def _check_factors(plan: LogicalPlan, source: "DataSource") -> None:
+    """An aggregate's factors are scalar integer columns of the schema,
+    and its plan has no ``head`` limit (SQL limits the answer, not the
+    rows an aggregate covers)."""
+    from ..core.encodings.base import code_dtype
+    from ..core.footer import ColKind, Sec
+    fv = source.footer(0)
+    missing = [c for c in plan.aggregate.columns()
+               if c not in source.column_set]
+    if missing:
+        raise ColumnNotFoundError(missing, source.column_names,
+                                  source.schema_path)
+    for c in plan.aggregate.columns():
+        i = fv.column_index(c)
+        dt = code_dtype(int(fv.arr(Sec.COL_LOGICAL, np.uint8)[i]))
+        if (int(fv.arr(Sec.COL_KIND, np.uint8)[i]) != int(ColKind.SCALAR)
+                or dt.kind not in "iu"):
+            raise TypeError(f"{plan.aggregate}: column {c!r} is not an "
+                            f"integer column ({dt})")
+    if plan.limit is not None:
+        raise ValueError(f"{plan.aggregate} takes no head(n) limit")
 
 
 def group_bounds(fv) -> np.ndarray:

@@ -1,5 +1,7 @@
 """Pallas TPU kernels for Bullion's compute hot-spots.
 
+  aggregate       — fused range filter and exact masked sum of products
+                    over int32 columns (the aggregate node's partials)
   bitunpack       — fixed-bit-width integer unpack (C6 FixedBitWidth/FOR
                     decode; the paper's SIMDFastBP128 analogue on the VPU)
   dequant         — fused per-feature dequantize + cast (C4 read path)

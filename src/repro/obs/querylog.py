@@ -83,6 +83,8 @@ class QueryRecord:
     columns: Optional[list] = None
     predicate: Optional[str] = None         # repr of the predicate, if any
     rows: int = 0                           # rows returned
+    aggregate: Optional[str] = None         # e.g. "sum_product(a, b)"
+    matched_rows: Optional[int] = None      # rows an aggregate covered
     result_bytes: int = 0                   # payload bytes returned
     wall_seconds: float = 0.0
     outcome: str = "ok"                     # "ok" | "error"

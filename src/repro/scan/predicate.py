@@ -28,6 +28,7 @@ filter kernel (``repro.kernels.filter``) consumes.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -334,13 +335,42 @@ class C:
 # ---------------------------------------------------------------------------
 
 
-def conjunctive_ranges(pred: Predicate) -> Optional[dict[str, tuple[float, float]]]:
-    """If ``pred`` is a pure conjunction of range/equality comparisons,
-    return closed float intervals per column (intersected); else None.
+def _int_interval(op: str, v) -> tuple:
+    """The integers ``x`` with ``x <op> v``, as a closed interval whose ends
+    are Python ints or infinities. A strict comparison closes to the next
+    integer; a NaN literal admits no integer."""
+    if isinstance(v, (int, np.integer)):
+        v = int(v)
+        lo_v = hi_v = v
+    else:
+        v = float(v)
+        if np.isnan(v):
+            return 1, 0
+        lo_v = math.ceil(v) if np.isfinite(v) else v    # least int >= v
+        hi_v = math.floor(v) if np.isfinite(v) else v   # greatest int <= v
+    if op == "==":
+        return lo_v, hi_v                # empty when v is not an integer
+    if op == "<":
+        return -math.inf, lo_v - 1
+    if op == "<=":
+        return -math.inf, hi_v
+    if op == ">":
+        return hi_v + 1, math.inf
+    return lo_v, math.inf                # >=
 
-    This is the planable form the Pallas batch filter kernel accepts:
-    ``lo[c] <= x[c] <= hi[c]`` AND-reduced over columns. Strict comparisons
-    are closed by one float64 ULP, exact for every representable literal.
+
+def conjunctive_ranges(pred: Predicate, int_columns=frozenset()
+                       ) -> Optional[dict[str, tuple[float, float]]]:
+    """If ``pred`` is a pure conjunction of range/equality comparisons,
+    return closed intervals per column (intersected); else None.
+
+    This is the planable form the Pallas kernels accept: ``lo[c] <= x[c]
+    <= hi[c]`` AND-reduced over columns. For a float column strict
+    comparisons are closed by one float64 ULP, exact for every
+    representable literal. For a column named in ``int_columns`` the
+    bounds are exact integers (Python ints, or infinities where a side is
+    open): ``x < 24`` is ``x <= 23``, and an interval may come out empty
+    (``lo > hi``).
     """
     leaves: list[Cmp] = []
 
@@ -357,6 +387,10 @@ def conjunctive_ranges(pred: Predicate) -> Optional[dict[str, tuple[float, float
     out: dict[str, tuple[float, float]] = {}
     for leaf in leaves:
         lo, hi = out.get(leaf.col, (-np.inf, np.inf))
+        if leaf.col in int_columns:
+            a, b = _int_interval(leaf.op, leaf.value)
+            out[leaf.col] = (max(lo, a), min(hi, b))
+            continue
         v = float(leaf.value)
         if leaf.op == "==":
             lo, hi = max(lo, v), min(hi, v)

@@ -8,6 +8,9 @@ protocol is strict request/response). Predicates are built with the normal
         res = cli.query("ads", where=C("id") == 12345,
                         columns=["ctr", "bid"])
         res.table["ctr"]        # numpy array, decoded
+        agg = cli.aggregate("sales", sum_product=("price", "discount"),
+                            where=C("qty") < 24)
+        agg.value, agg.rows     # exact int, rows it covered
 
 Spin up several clients (or threads each owning one) for concurrency —
 the server is thread-per-session and all sessions share its bounded pool.
@@ -55,6 +58,7 @@ class ClientResult:
     trace_id: Optional[str] = None
     degraded: bool = False        # server dropped/masked quarantined pages
     degraded_rows: int = 0
+    value: Optional[int] = None   # an aggregate's value (``rows``: covered)
 
 
 class ServeClient:
@@ -152,6 +156,29 @@ class ServeClient:
                             trace_id=self.trace_id,
                             degraded=bool(resp.get("degraded")),
                             degraded_rows=int(resp.get("degraded_rows") or 0))
+
+    def aggregate(self, dataset: str, *, sum_product: Sequence[str],
+                  where: Optional[Predicate] = None,
+                  tenant: str = "default",
+                  io_depth: Optional[int] = None) -> ClientResult:
+        """The exact ``sum(a * b)`` over the rows that pass ``where``
+        (``DatasetServer.aggregate``): ``value`` and the ``rows`` it
+        covered, with an empty ``table``."""
+        req = {"op": "aggregate", "dataset": dataset,
+               "sum_product": list(sum_product),
+               "where": wire.encode_predicate(where), "tenant": tenant,
+               "io_depth": io_depth}
+        if self.trace_id is not None:
+            req["trace"] = {"id": self.trace_id}
+        resp = self._rpc(req)
+        return ClientResult(table={}, rows=resp["rows"],
+                            cache_hit=resp["cache_hit"],
+                            fingerprint=resp["fingerprint"],
+                            wall_seconds=resp["wall_seconds"],
+                            trace_id=self.trace_id,
+                            degraded=bool(resp.get("degraded")),
+                            degraded_rows=int(resp.get("degraded_rows") or 0),
+                            value=int(resp["value"]))
 
     def profile(self, path: Optional[str] = None) -> Profile:
         """Merge the client-side RPC spans with every server span this

@@ -46,7 +46,7 @@ from typing import Optional, Sequence
 from ..core import integrity as _integrity
 from ..dataset import executor
 from ..dataset.core import Dataset
-from ..dataset.plan import LogicalPlan
+from ..dataset.plan import LogicalPlan, SumProduct
 from ..dataset.source import DataSource, PathSpec, discover
 from ..obs import metrics as _metrics
 from ..obs import querylog as _querylog
@@ -76,6 +76,9 @@ class QueryResult:
     spans: Optional[list] = None  # wall-ts span dicts (wire trace requests)
     degraded: bool = False        # quarantined pages degraded this result
     degraded_rows: int = 0        # exact rows dropped/masked (IOStats delta)
+    # an aggregate's exact value (``rows`` is then the rows it covered and
+    # ``table`` is empty); None for a query that returns rows
+    value: Optional[int] = None
 
 
 @dataclass
@@ -176,11 +179,14 @@ class TenantBudget:
 
 
 class DatasetServer:
-    """Serve select/where/head plans over attached Bullion datasets.
+    """Serve select/where/head plans, and aggregates over a filter, over
+    attached Bullion datasets.
 
-    In-process: ``server.query("ads", where=C("id") == 7)``. Over a local
-    socket: ``server.serve(path)`` + ``ServeClient(path)``. Both funnel
-    into the same bounded executor pool."""
+    In-process: ``server.query("ads", where=C("id") == 7)`` or
+    ``server.aggregate("sales", sum_product=("price", "discount"),
+    where=...)``. Over a local socket: ``server.serve(path)`` +
+    ``ServeClient(path)``. All funnel into the same bounded executor pool,
+    plan cache and query log."""
 
     def __init__(self, datasets: Optional[dict[str, PathSpec]] = None, *,
                  max_workers: int = 4, plan_cache_size: int = 64,
@@ -249,19 +255,24 @@ class DatasetServer:
     # -- planning ---------------------------------------------------------------
     def _build_plan(self, columns: Optional[Sequence[str]],
                     where: Optional[Predicate],
-                    head: Optional[int]) -> LogicalPlan:
+                    head: Optional[int],
+                    aggregate: Optional[SumProduct] = None) -> LogicalPlan:
         return LogicalPlan(
             columns=tuple(columns) if columns is not None else None,
-            predicate=where, limit=head)
+            predicate=where, limit=head, aggregate=aggregate)
 
     def prepare(self, dataset: str, *,
                 columns: Optional[Sequence[str]] = None,
                 where: Optional[Predicate] = None,
-                head: Optional[int] = None) -> tuple[Dataset, str, bool]:
+                head: Optional[int] = None,
+                aggregate: Optional[SumProduct] = None
+                ) -> tuple[Dataset, str, bool]:
         """Resolve (and cache) the prepared plan for a query shape without
-        executing it. Returns (dataset instance, fingerprint, cache hit)."""
+        executing it. Returns (dataset instance, fingerprint, cache hit).
+        An aggregate is part of the shape: its plan never shares an entry
+        with a projection's."""
         source = self._source(dataset)
-        plan = self._build_plan(columns, where, head)
+        plan = self._build_plan(columns, where, head, aggregate)
         return self._cache.get_or_prepare(dataset, source, plan)
 
     def explain(self, dataset: str, *,
@@ -281,10 +292,13 @@ class DatasetServer:
                tenant: str = DEFAULT_TENANT,
                io_depth: Optional[int] = None,
                trace_id: Optional[str] = None,
-               collect_spans: bool = False) -> "Future[QueryResult]":
+               collect_spans: bool = False,
+               sum_product: Optional[Sequence[str]] = None
+               ) -> "Future[QueryResult]":
         """Queue a query on the bounded pool and return its Future.
         Admission control happens here: the pool caps concurrent
         executions, and the submit-time queue depth is recorded.
+        ``sum_product=(a, b)`` makes it an aggregate (``aggregate``).
         ``trace_id`` tags the query's spans and its query-log record;
         ``collect_spans=True`` additionally runs the query under a scoped
         tracer and returns the finished spans on the result (what the wire
@@ -295,9 +309,10 @@ class DatasetServer:
             self._pending += 1
             depth = self._pending
         _metrics.histogram("bullion.serve.queue_depth").observe(depth)
+        agg = SumProduct(*sum_product) if sum_product is not None else None
         fut = self._pool.submit(self._run, dataset, columns, where, head,
                                 tenant, io_depth, trace_id, collect_spans,
-                                queued=time.perf_counter())
+                                queued=time.perf_counter(), aggregate=agg)
         fut.add_done_callback(self._done)
         return fut
 
@@ -316,6 +331,22 @@ class DatasetServer:
                            trace_id=trace_id,
                            collect_spans=collect_spans).result(timeout)
 
+    def aggregate(self, dataset: str, *, sum_product: Sequence[str],
+                  where: Optional[Predicate] = None,
+                  tenant: str = DEFAULT_TENANT,
+                  io_depth: Optional[int] = None,
+                  timeout: Optional[float] = None,
+                  trace_id: Optional[str] = None,
+                  collect_spans: bool = False) -> QueryResult:
+        """Blocking aggregate: the exact ``sum(a * b)`` over the rows that
+        pass ``where``, with ``sum_product=(a, b)`` two integer columns
+        (``Dataset.aggregate``). The result's ``value`` is the sum and
+        ``rows`` the rows it covered."""
+        return self.submit(dataset, where=where, tenant=tenant,
+                           io_depth=io_depth, trace_id=trace_id,
+                           collect_spans=collect_spans,
+                           sum_product=sum_product).result(timeout)
+
     def _done(self, fut: Future) -> None:
         with self._lock:
             self._pending -= 1
@@ -331,7 +362,8 @@ class DatasetServer:
     def _run(self, dataset: str, columns, where, head, tenant: str,
              io_depth: Optional[int], trace_id: Optional[str] = None,
              collect_spans: bool = False, *,
-             queued: Optional[float] = None) -> QueryResult:
+             queued: Optional[float] = None,
+             aggregate: Optional[SumProduct] = None) -> QueryResult:
         """Run one query on a pool thread. ``wall_seconds`` starts here;
         the wait for the thread since ``queued`` (the submit instant) is
         the ``serve.query`` span's ``queued_ms``."""
@@ -340,7 +372,8 @@ class DatasetServer:
             ts=time.time(), origin="serve", dataset=dataset, tenant=tenant,
             columns=list(columns) if columns is not None else None,
             predicate=repr(where) if where is not None else None,
-            trace_id=trace_id)
+            trace_id=trace_id,
+            aggregate=str(aggregate) if aggregate is not None else None)
         # the scoped tracer costs span allocations, so it runs only when a
         # caller asked for spans, a slow-query threshold is armed, or a
         # process-wide recording is already on — the default serve hot path
@@ -359,7 +392,8 @@ class DatasetServer:
                 tracer = scope.__enter__()
             try:
                 ds, fp, hit = self.prepare(dataset, columns=columns,
-                                           where=where, head=head)
+                                           where=where, head=head,
+                                           aggregate=aggregate)
                 rec.fingerprint, rec.cache_hit = fp, hit
                 source = self._sources[dataset]
                 budget = self.tenant_budget(tenant)
@@ -375,7 +409,13 @@ class DatasetServer:
                     if trace_id is not None:
                         sp.set(trace_id=trace_id)
                 with sp:
-                    table = ds.to_table(io_depth=held)
+                    if aggregate is None:
+                        table, value = ds.to_table(io_depth=held), None
+                    else:
+                        table = {}
+                        value, rec.matched_rows = ds.aggregate(
+                            sum_product=aggregate.columns(),
+                            io_depth=held)
                 # exact for this query while queries on the dataset don't
                 # overlap (the source accounting is dataset-wide)
                 rec.io = dataclasses.asdict(source.stats.delta(before))
@@ -402,8 +442,11 @@ class DatasetServer:
                 self._queries += 1
             _metrics.counter("bullion.serve.queries").inc()
             _metrics.histogram("bullion.serve.wall_seconds").observe(wall)
-            return QueryResult(table=table, rows=rec.rows, cache_hit=hit,
-                               fingerprint=fp, wall_seconds=wall,
+            return QueryResult(table=table,
+                               rows=rec.rows if value is None
+                               else rec.matched_rows,
+                               cache_hit=hit, fingerprint=fp,
+                               wall_seconds=wall, value=value,
                                tenant=tenant, trace_id=trace_id,
                                spans=spans_out if collect_spans else None,
                                degraded=rec.degraded,
@@ -553,15 +596,19 @@ class DatasetServer:
         return {"ok": False, "error": f"{type(e).__name__}: {e}"}
 
     def _answer(self, req: dict, res: QueryResult) -> dict:
-        """The wire answer to a served query: its table encoded."""
+        """The wire answer to a served query: its table encoded, or an
+        aggregate's value (a JSON integer, exact at any size)."""
         try:
             resp = {"ok": True, "rows": res.rows,
                     "cache_hit": res.cache_hit,
                     "fingerprint": res.fingerprint,
                     "wall_seconds": res.wall_seconds,
                     "degraded": res.degraded,
-                    "degraded_rows": res.degraded_rows,
-                    "table": wire.encode_table(res.table)}
+                    "degraded_rows": res.degraded_rows}
+            if res.value is None:
+                resp["table"] = wire.encode_table(res.table)
+            else:
+                resp["value"] = res.value
         except Exception as e:
             return self._failed(req, e)
         if req.get("trace"):
@@ -569,8 +616,8 @@ class DatasetServer:
         return resp
 
     def _dispatch(self, req: dict):
-        """The answer to one request: a dict, or for a query its
-        ``QueryResult``, which ``_answer`` encodes."""
+        """The answer to one request: a dict, or for a query or an
+        aggregate its ``QueryResult``, which ``_answer`` encodes."""
         op = req.get("op")
         if op == "ping":
             return {"ok": True, "pong": True}
@@ -595,6 +642,14 @@ class DatasetServer:
                 req["dataset"], columns=req.get("columns"),
                 where=wire.decode_predicate(req.get("where")),
                 head=req.get("head"),
+                tenant=req.get("tenant", DEFAULT_TENANT),
+                io_depth=req.get("io_depth"),
+                trace_id=trace_req.get("id"), collect_spans=bool(trace_req))
+        if op == "aggregate":
+            trace_req = req.get("trace") or {}
+            return self.aggregate(
+                req["dataset"], sum_product=req["sum_product"],
+                where=wire.decode_predicate(req.get("where")),
                 tenant=req.get("tenant", DEFAULT_TENANT),
                 io_depth=req.get("io_depth"),
                 trace_id=trace_req.get("id"), collect_spans=bool(trace_req))

@@ -334,6 +334,56 @@ def test_conjunctive_ranges():
     assert conjunctive_ranges((C("a") > 0) | (C("b") > 0)) is None
 
 
+def test_conjunctive_ranges_integer_columns_are_exact():
+    pred = ((C("q") < 24) & (C("d") >= 2) & (C("d") <= 4) & (C("s") > 8400)
+            & (C("f") < 0.5))
+    r = conjunctive_ranges(pred, int_columns={"q", "d", "s"})
+    assert r["q"] == (-np.inf, 23) and r["d"] == (2, 4)
+    assert r["s"] == (8401, np.inf)
+    assert all(type(v) is int for v in (r["q"][1], *r["d"], r["s"][0]))
+    assert r["f"] == conjunctive_ranges(C("f") < 0.5)["f"]
+    # float literals close to the integers inside them
+    r = conjunctive_ranges((C("q") < 23.5) & (C("q") >= -0.5)
+                           & (C("p") <= 7.9) & (C("p") > 2.1),
+                           int_columns={"q", "p"})
+    assert r == {"q": (0, 23), "p": (3, 7)}
+    # no integer satisfies these
+    r = conjunctive_ranges((C("a") == 2.5) & (C("b") > float("nan"))
+                           & (C("c") > 5) & (C("c") < 6),
+                           int_columns={"a", "b", "c"})
+    assert all(lo > hi for lo, hi in r.values())
+    # literals past float64's integers stay exact
+    big = 2 ** 60 + 1
+    assert conjunctive_ranges(C("k") < big, int_columns={"k"}) == \
+        {"k": (-np.inf, big - 1)}
+
+
+def test_conjunctive_ranges_integer_bounds_agree_with_numpy():
+    from repro.scan.predicate import Cmp
+    rng = np.random.default_rng(11)
+    x = np.arange(-40, 41, dtype=np.int32)
+    for _ in range(300):
+        op = ["<", "<=", ">", ">=", "=="][rng.integers(5)]
+        v = float(rng.integers(-30, 30)) + [0.0, 0.5, -0.25][rng.integers(3)]
+        pred = Cmp("x", op, v)
+        lo, hi = conjunctive_ranges(pred, int_columns={"x"})["x"]
+        assert np.array_equal((x >= lo) & (x <= hi),
+                              pred.mask({"x": x})), (op, v)
+
+
+def test_conjunctive_ranges_float_intervals_unchanged():
+    """The float filter path's intervals are what they were: strict sides
+    closed by one float64 ULP, whatever other columns are integer."""
+    pred = ((C("a") >= 1) & (C("a") < 5) & (C("b") > 0.25)
+            & (C("b") <= 0.75) & (C("c") == 2.5))
+    want = {"a": (1.0, float(np.nextafter(5.0, -np.inf))),
+            "b": (float(np.nextafter(0.25, np.inf)), 0.75),
+            "c": (2.5, 2.5)}
+    assert conjunctive_ranges(pred) == want
+    assert conjunctive_ranges(pred, int_columns={"z"}) == want
+    assert all(type(v) is float for lo_hi in want.values() for v in lo_hi)
+
+
 def test_zone_map_soundness_fuzz():
     """maybe_any must never return False for a page that contains a match."""
     rng = np.random.default_rng(7)

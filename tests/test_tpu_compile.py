@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.aggregate.kernel import BLOCK_ROWS, sum_product_pallas
 from repro.kernels.bitunpack.kernel import bitunpack_pallas
 from repro.kernels.dequant.kernel import dequant_pallas
 from repro.kernels.filter.kernel import range_mask_pallas
@@ -76,3 +77,19 @@ def test_bitunpack_compiles_for_v5e(one_chip):
     fn = jax.jit(lambda p: bitunpack_pallas(p, 7, interpret=False))
     text = _compile_text(fn, ((2048, 7), jnp.uint32), sharding=one_chip)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n_cols", [2, 4])
+def test_sum_product_compiles_for_v5e(one_chip, n_cols):
+    """The fused filter-and-sum kernel at one row group: TPC-H Q6 reads
+    four int32 columns (three predicate columns and the price)."""
+    lanes = N_ROWS // 128
+    assert lanes % BLOCK_ROWS == 0
+    args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in ((n_cols, lanes, 128), (2 * n_cols + 1, 1, 128))]
+    lowered = sum_product_pallas.lower(*args, a=n_cols - 1, b=1,
+                                       interpret=False)
+    assert 'kernel_name = "sum_product"' in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "HloModule jit_sum_product_pallas" in text
