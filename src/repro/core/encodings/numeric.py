@@ -14,7 +14,7 @@ where the paper defines an in-place deletion-masking rule (§2.1):
 from __future__ import annotations
 
 import struct
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -376,6 +376,32 @@ class FOR(Encoding):
         u = unpack_bits(payload, n, width)
         u[positions] = 0  # decodes to base; page DV hides it
         return bytes(header), pack_bits(u, width)
+
+
+class BitPacked(NamedTuple):
+    """A FixedBitWidth or FOR blob, read from its header alone: value i is
+    ``base`` plus the i-th ``width``-bit field of ``payload``, a
+    little-endian bitstream (``pack_bits``), cast to ``dtype``."""
+
+    dtype: np.dtype
+    n: int
+    base: int
+    width: int
+    payload: memoryview
+
+
+def bit_packed(blob: bytes | memoryview) -> Optional[BitPacked]:
+    """The parts of a FixedBitWidth (base 0) or FOR blob; None for a blob
+    of any other encoding. Nothing is decoded."""
+    eid, header, payload, _ = unframe(blob)
+    if eid == FixedBitWidth.eid:
+        code, n, width = struct.unpack_from("<BQB", header)
+        base = 0
+    elif eid == FOR.eid:
+        code, n, base, width = struct.unpack_from("<BQqB", header)
+    else:
+        return None
+    return BitPacked(code_dtype(code), n, base, width, payload)
 
 
 class Constant(Encoding):

@@ -9,11 +9,14 @@ out in ``execute_group``, which orders the stages exactly once:
     deletion-mask -> dequantize -> filter -> gather
 
 ``decode_group`` is the pread+decode+mask+dequantize core (moved here from
-``BullionReader.project``); ``execute_group`` layers predicate evaluation
-(NumPy or the Pallas batch filter kernel) and raw-row-id selection on top.
-``aggregate_group`` is the same pipeline ending in a partial aggregate in
-place of the gather: the group's sum of products and matching-row count,
-from the fused Pallas filter-and-sum kernel or from NumPy, exact either way.
+``BullionReader.project``): ``read_pages``, then ``decode_pages``.
+``execute_group`` layers predicate evaluation (NumPy or the Pallas batch
+filter kernel) and raw-row-id selection on top. ``aggregate_group`` is the
+same pipeline ending in a partial aggregate in place of the gather: the
+group's sum of products and matching-row count, from the fused Pallas
+filter-and-sum kernel or from NumPy, exact either way. Where every page it
+reads is bit-packed (FixedBitWidth or FOR) and whole, the kernel takes the
+pages' packed words and the host decodes nothing.
 """
 
 from __future__ import annotations
@@ -21,12 +24,14 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import dataclass, field
-from typing import TYPE_CHECKING, Callable, Optional, Sequence
+from typing import TYPE_CHECKING, Callable, NamedTuple, Optional, Sequence
 
 import numpy as np
 
 from ..core import integrity as _integrity
 from ..core import pages as pages_mod
+from ..core.encodings.base import code_dtype
+from ..core.encodings.numeric import bit_packed
 from ..core.footer import ColKind, PageType, Sec, ShardCorruptError
 from ..core.quantization import QuantMode, dequantize
 from ..obs import metrics as _metrics
@@ -113,7 +118,6 @@ def _mask_fill(fv, col: int, rows: int):
     corruption policy: scalar/media_ref pages decode to zeros of the
     storage dtype, list pages to empty arrays, string pages to empty
     strings — same row count and types as a healthy decode."""
-    from ..core.encodings.base import code_dtype
     kind = int(fv.arr(Sec.COL_KIND, np.uint8)[col])
     dt = code_dtype(int(fv.arr(Sec.COL_DTYPE, np.uint8)[col]))
     if kind == int(ColKind.LIST):
@@ -143,19 +147,42 @@ def decode_group(reader: "BullionReader", names: Sequence[str], group: int, *,
     disabled the spans are shared no-ops and the stage order is the only
     (behavior-identical) difference from an uninstrumented decode.
     """
+    return decode_pages(reader, names, group,
+                        read_pages(reader, names, group, pages),
+                        drop_deleted=drop_deleted, dequant=dequant,
+                        pages=pages, align_raw=align_raw,
+                        masked_out=masked_out)
+
+
+def read_pages(reader: "BullionReader", names: Sequence[str], group: int,
+               pages: Optional[Sequence[int]] = None) -> dict:
+    """The coalesced pread of one row group's pages of ``names`` (the
+    ``decode.pread`` span): page id -> verified page bytes."""
     fv = reader.footer
-    cols = [fv.column_index(n) for n in names]
-    kinds = fv.arr(Sec.COL_KIND, np.uint8)
-    flags = fv.arr(Sec.PAGE_FLAGS, np.uint8)
-    page_rows = fv.arr(Sec.PAGE_ROWS, np.uint32)
     wanted: list[int] = []
-    for c in cols:
-        wanted.extend(_chunk_page_ids(fv, group, c, pages))
+    for n in names:
+        wanted.extend(_chunk_page_ids(fv, group, fv.column_index(n), pages))
     sp = _trace.span("decode.pread", cat="io", group=group, pages=len(wanted))
     with sp:
         raw = reader._read_pages(wanted)
         if sp.enabled:
             sp.set(bytes=sum(len(b) for b in raw.values()))
+    return raw
+
+
+def decode_pages(reader: "BullionReader", names: Sequence[str], group: int,
+                 raw: dict, *, drop_deleted: bool = True,
+                 dequant: bool = True,
+                 pages: Optional[Sequence[int]] = None,
+                 align_raw: bool = False,
+                 masked_out: Optional[set] = None) -> dict:
+    """``decode_group`` after its pread: decode the pages ``read_pages``
+    returned."""
+    fv = reader.footer
+    cols = [fv.column_index(n) for n in names]
+    kinds = fv.arr(Sec.COL_KIND, np.uint8)
+    flags = fv.arr(Sec.PAGE_FLAGS, np.uint8)
+    page_rows = fv.arr(Sec.PAGE_ROWS, np.uint32)
     out: dict = {}
 
     def _dec(c: int, p: int):
@@ -309,9 +336,8 @@ def eval_mask(pred: Predicate, tbl: dict,
 _INT32 = (-(1 << 31), (1 << 31) - 1)
 
 
-def _int32_safe(x) -> bool:
-    """Does every value of this decoded column's dtype fit in int32?"""
-    dt = getattr(x, "dtype", None)
+def _int32_safe(dt) -> bool:
+    """Does every value of this dtype (None: not an array) fit in int32?"""
     return dt is not None and (dt.kind == "i" and dt.itemsize <= 4
                                or dt.kind == "u" and dt.itemsize <= 2)
 
@@ -345,6 +371,65 @@ def _host_sum_product(x: np.ndarray, y: np.ndarray) -> int:
     return (int((p >> 32).sum()) << 32) + int((p & 0xFFFFFFFF).sum())
 
 
+class _KernelCall(NamedTuple):
+    """The fused kernel's columns, their closed int32 intervals, and the
+    factors' positions among them (``a`` is split into limbs)."""
+
+    names: list
+    lo: list
+    hi: list
+    a: int
+    b: int
+
+
+def _kernel_call(pred: Optional[Predicate], dtypes: dict, n: int,
+                 factors: Sequence[str], bounds: Optional[Sequence[int]],
+                 block: int) -> Optional[_KernelCall]:
+    """The fused kernel's call over ``n`` rows whose columns have
+    ``dtypes``, when the predicate is a conjunction of ranges, every column
+    it reads is an integer column that fits int32, and the factors'
+    magnitudes ``bounds`` keep its lane sums exact in programs of ``block``
+    rows; None where only NumPy is exact."""
+    from ..kernels.aggregate import ops as kernel_ops
+    a, b = factors
+    cols = pred.columns() if pred is not None else set()
+    if bounds is None or not all(_int32_safe(dtypes[c])
+                                 for c in {*cols, a, b}):
+        return None
+    ranges = conjunctive_ranges(pred, int_columns=cols) \
+        if pred is not None else {}
+    if ranges is None:
+        return None
+    if kernel_ops.exact_for(n, bounds[0], bounds[1], block):
+        order = (a, b)
+    elif kernel_ops.exact_for(n, bounds[1], bounds[0], block):
+        order = (b, a)
+    else:
+        return None
+    names = list(dict.fromkeys([*ranges, *order]))
+    return _KernelCall(
+        names, [max(ranges.get(c, _INT32)[0], _INT32[0]) for c in names],
+        [min(ranges.get(c, _INT32)[1], _INT32[1]) for c in names],
+        names.index(order[0]), names.index(order[1]))
+
+
+def _run_kernel(call: _KernelCall, stage: Callable) -> tuple[int, int]:
+    """One round trip of the fused kernel, its steps timed apart:
+    ``stage()`` lays out and puts the call's columns."""
+    from ..kernels.aggregate import ops as kernel_ops
+    # an interval outside int32 admits no int32 value
+    if any(lo > hi for lo, hi in zip(call.lo, call.hi)):
+        return 0, 0
+    with _trace.span("aggregate.stage", cat="aggregate"):
+        _metrics.counter("bullion.aggregate.kernel_calls").inc()
+        staged = stage()
+    with _trace.span("aggregate.launch", cat="aggregate"):
+        out = kernel_ops.launch(staged)
+    with _trace.span("aggregate.fetch", cat="aggregate"):
+        del staged
+        return kernel_ops.fetch(out)
+
+
 def eval_sum_product(pred: Optional[Predicate], tbl: dict,
                      factors: Sequence[str],
                      bounds: Optional[Sequence[int]],
@@ -359,26 +444,16 @@ def eval_sum_product(pred: Optional[Predicate], tbl: dict,
     from ..kernels.aggregate import ops as kernel_ops
     a, b = factors
     n = len(tbl[a])
-    cols = pred.columns() if pred is not None else set()
-    ranges = conjunctive_ranges(
-        pred, int_columns={c for c in cols if _int32_safe(tbl[c])}) \
-        if pred is not None else {}
-    order = None
-    if bounds is not None:
-        if kernel_ops.exact_for(n, bounds[0], bounds[1]):
-            order = (a, b)
-        elif kernel_ops.exact_for(n, bounds[1], bounds[0]):
-            order = (b, a)
-    kernel_ok = (ranges is not None and rows_mask is None
-                 and order is not None
-                 and all(_int32_safe(tbl[c]) for c in {*cols, a, b}))
-    if use_kernel and not kernel_ok:
+    call = None if rows_mask is not None else _kernel_call(
+        pred, {c: getattr(v, "dtype", None) for c, v in tbl.items()}, n,
+        factors, bounds, kernel_ops.BLOCK_N)
+    if use_kernel and call is None:
         raise ValueError(
             "the aggregate kernel requires a conjunctive range predicate "
             "over int32 columns, no pinned rows, and factors whose zone "
             "maps keep its int32 lane sums exact")
     if use_kernel is None:
-        use_kernel = kernel_ok
+        use_kernel = call is not None
     if not use_kernel:
         _metrics.counter("bullion.aggregate.host_groups").inc()
         mask = evaluate(pred, tbl) if pred is not None \
@@ -388,24 +463,84 @@ def eval_sum_product(pred: Optional[Predicate], tbl: dict,
         return (_host_sum_product(np.asarray(tbl[a])[mask],
                                   np.asarray(tbl[b])[mask]),
                 int(mask.sum()))
-    # an interval outside int32 admits no int32 value
-    if any(max(lo, _INT32[0]) > min(hi, _INT32[1])
-           for lo, hi in ranges.values()):
-        return 0, 0
-    with _trace.span("aggregate.stage", cat="aggregate"):
-        names = list(dict.fromkeys([*ranges, *order]))
-        full = (_INT32[0], _INT32[1])
-        lo = [max(ranges.get(c, full)[0], _INT32[0]) for c in names]
-        hi = [min(ranges.get(c, full)[1], _INT32[1]) for c in names]
-        _metrics.counter("bullion.aggregate.kernel_calls").inc()
-        staged = kernel_ops.stage(
-            np.stack([np.asarray(tbl[c], np.int32) for c in names]), lo, hi,
-            names.index(order[0]), names.index(order[1]))
-    with _trace.span("aggregate.launch", cat="aggregate"):
-        out = kernel_ops.launch(staged)
-    with _trace.span("aggregate.fetch", cat="aggregate"):
-        del staged
-        return kernel_ops.fetch(out)
+    return _run_kernel(call, lambda: kernel_ops.stage(
+        np.stack([np.asarray(tbl[c], np.int32) for c in call.names]),
+        call.lo, call.hi, call.a, call.b))
+
+
+def _packed_pages(parts: list, page_rows: list, dtype: np.dtype) -> bool:
+    """Are these one column's pages, as ``bit_packed`` read them, whole
+    pages the packed kernel can take: bit-packed at one width of 1 to 31
+    bits over the column's dtype, each holding its page's rows, all but the
+    last a whole number of the kernel's rows, and every base an int32?"""
+    from ..kernels.aggregate.kernel import ROW_N
+    if any(p is None for p in parts):
+        return False
+    width = parts[0].width
+    return (1 <= width <= 31
+            and all(p.width == width and p.dtype == dtype and p.n == rows
+                    and _INT32[0] <= p.base <= _INT32[1]
+                    for p, rows in zip(parts, page_rows))
+            and all(p.n % ROW_N == 0 for p in parts[:-1]))
+
+
+def _packed_sum_product(reader: "BullionReader", group: int,
+                        names: Sequence[str], raw: dict, *,
+                        predicate: Optional[Predicate],
+                        factors: Sequence[str],
+                        bounds: Optional[Sequence[int]],
+                        pages: Optional[Sequence[int]]
+                        ) -> Optional[tuple[int, int]]:
+    """The group's partial aggregate straight from the packed words of its
+    pages of ``names`` (read into ``raw``), unpacked by the kernel: the
+    host joins payloads and decodes nothing. None where a page the kernel would read is not a whole bit-packed page
+    of a plain int32-safe column, carries a deletion vector or was
+    quarantined, or where only NumPy is exact: the group is then decoded."""
+    from ..kernels.aggregate import ops as kernel_ops
+    fv = reader.footer
+    cols = {c: fv.column_index(c) for c in names}
+    col_dtypes = fv.arr(Sec.COL_DTYPE, np.uint8)
+    dtypes = {c: code_dtype(int(col_dtypes[i])) for c, i in cols.items()}
+    page_rows = fv.arr(Sec.PAGE_ROWS, np.uint32)
+    pids = {c: _chunk_page_ids(fv, group, i, pages) for c, i in cols.items()}
+    n = sum(int(page_rows[p]) for p in pids[factors[0]])
+    call = _kernel_call(predicate, dtypes, n, factors, bounds,
+                        kernel_ops.TILE_N)
+    kinds = fv.arr(Sec.COL_KIND, np.uint8)
+    if call is None or any(
+            kinds[cols[c]] != ColKind.SCALAR
+            or reader.quant_spec(cols[c]).mode != QuantMode.NONE
+            or any(p not in raw or fv.deletion_vector(p) is not None
+                   for p in pids[c])
+            for c in call.names):
+        return None
+    flags = fv.arr(Sec.PAGE_FLAGS, np.uint8)
+    columns, widths = [], []
+    for c in call.names:
+        ps = pids[c]
+        with _trace.span("decode.decode", cat="decode", column=c,
+                         pages=len(ps), encoding="packed"):
+            parts = [bit_packed(raw[p])
+                     if int(flags[p]) & 0x7F == PageType.SCALAR else None
+                     for p in ps]
+            if not _packed_pages(parts, [int(page_rows[p]) for p in ps],
+                                 dtypes[c]):
+                return None
+            widths.append(parts[0].width)
+            columns.append(kernel_ops.pack_column(
+                [(p.payload, p.n, p.base) for p in parts], parts[0].width))
+
+    def stage():
+        _metrics.counter("bullion.aggregate.packed_groups").inc()
+        return kernel_ops.stage_packed(columns, widths, n, call.lo, call.hi,
+                                       call.a, call.b)
+
+    sp = _trace.span("exec.aggregate", cat="exec", group=group)
+    with sp:
+        value, count = _run_kernel(call, stage)
+        if sp.enabled:
+            sp.set(rows_in=n, rows_out=count)
+    return value, count
 
 
 # ---------------------------------------------------------------------------
@@ -639,9 +774,17 @@ def _aggregate_group_once(reader: "BullionReader", group: int, *,
     if pages is not None and not len(pages):
         return 0, 0
     pred_cols = sorted(predicate.columns()) if predicate is not None else []
-    tbl = decode_group(reader, list(dict.fromkeys([*pred_cols, *factors])),
-                       group, drop_deleted=drop_deleted, dequant=True,
-                       pages=pages, align_raw=not drop_deleted,
+    names = list(dict.fromkeys([*pred_cols, *factors]))
+    raw = read_pages(reader, names, group, pages)
+    bounds = factor_bounds(fv, group, factors)
+    if rows is None and use_kernel is not False:
+        packed = _packed_sum_product(reader, group, names, raw,
+                                     predicate=predicate, factors=factors,
+                                     bounds=bounds, pages=pages)
+        if packed is not None:
+            return packed
+    tbl = decode_pages(reader, names, group, raw, drop_deleted=drop_deleted,
+                       dequant=True, pages=pages, align_raw=not drop_deleted,
                        masked_out=masked_out)
     rows_mask = None
     if rows is not None:
@@ -649,9 +792,8 @@ def _aggregate_group_once(reader: "BullionReader", group: int, *,
                                *_row_space(fv, group, pages, drop_deleted))
     sp = _trace.span("exec.aggregate", cat="exec", group=group)
     with sp:
-        value, count = eval_sum_product(
-            predicate, tbl, factors, factor_bounds(fv, group, factors),
-            use_kernel, rows_mask)
+        value, count = eval_sum_product(predicate, tbl, factors, bounds,
+                                        use_kernel, rows_mask)
         if sp.enabled:
             sp.set(rows_in=len(tbl[factors[0]]), rows_out=count)
     return value, count

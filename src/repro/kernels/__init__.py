@@ -1,9 +1,8 @@
 """Pallas TPU kernels for Bullion's compute hot-spots.
 
   aggregate       — fused range filter and exact masked sum of products
-                    over int32 columns (the aggregate node's partials)
-  bitunpack       — fixed-bit-width integer unpack (C6 FixedBitWidth/FOR
-                    decode; the paper's SIMDFastBP128 analogue on the VPU)
+                    over int32 columns, or over their FixedBitWidth/FOR
+                    pages unpacked on the VPU (the aggregate node's partials)
   dequant         — fused per-feature dequantize + cast (C4 read path)
   filter          — conjunctive range filter for predicate pushdown (the
                     scan subsystem's batch row-survivor mask)

@@ -1,48 +1,53 @@
-"""Public wrapper: pads and lays out a row group's int32 columns, runs the
-kernel, and adds its per-lane partial sums exactly on the host.
+"""Public wrapper: pads and lays out a row group's int32 columns, or their
+bit-packed pages, runs the kernel, and adds its per-lane partial sums
+exactly on the host.
 
-``sum_product`` is the whole device round trip; ``stage``, ``launch`` and
-``fetch`` are its three steps, for a caller that times them apart.
-``exact_for`` says whether a call's int32 accumulators are exact for given
-factor magnitudes.
+``sum_product`` and ``sum_product_packed`` are whole device round trips;
+``stage`` or ``stage_packed``, ``launch`` and ``fetch`` are their steps, for
+a caller that times them apart. ``pack_column`` lays one column's pages out
+as the packed kernel reads them. ``exact_for`` says whether a call's int32
+accumulators are exact for given factor magnitudes.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .. import interpret
-from .kernel import (BLOCK_N, LANES, LIMB_BITS, LIMB_MASK, SUBLANES,
-                     sum_product_pallas)
+from .kernel import (BLOCK_N, LANES, LIMB_BITS, LIMB_MASK, ROW_N, SUBLANES,
+                     TILE_GROUPS, TILE_N, sum_product_pallas,
+                     sum_product_pallas_packed)
 
 INT32_MAX = (1 << 31) - 1
 
 
-def padded_rows(n: int) -> int:
-    return max(BLOCK_N, -(-n // BLOCK_N) * BLOCK_N)
+def padded_rows(n: int, block: int = BLOCK_N) -> int:
+    return max(block, -(-n // block) * block)
 
 
-def exact_for(n: int, max_abs_a: int, max_abs_b: int) -> bool:
+def exact_for(n: int, max_abs_a: int, max_abs_b: int,
+              block: int = BLOCK_N) -> bool:
     """Are the kernel's int32 lane sums exact for ``n`` rows whose factors
-    are at most ``max_abs_a`` and ``max_abs_b`` in magnitude? Each of the
-    8 x 128 lanes adds ``n / 1024`` (padded) limb products: the low limb is
+    are at most ``max_abs_a`` and ``max_abs_b`` in magnitude, in programs
+    of ``block`` rows (``TILE_N`` for the packed kernel)? Each of the 8 x
+    128 lanes adds ``n / 1024`` (padded) limb products: the low limb is
     below 2**12, the high limb at most ``(max_abs_a >> 12) + 1``."""
-    per_lane = padded_rows(n) // (SUBLANES * LANES)
+    per_lane = padded_rows(n, block) // (SUBLANES * LANES)
     limb = max(LIMB_MASK, (int(max_abs_a) >> LIMB_BITS) + 1)
     return per_lane * limb * int(max_abs_b) <= INT32_MAX
 
 
 class Staged(NamedTuple):
-    """A call's inputs on the device, and which of its columns multiply."""
+    """A call's kernel, its inputs on the device, and its static
+    arguments (which of its columns multiply, and how they are packed)."""
 
-    cols: jax.Array
-    params: jax.Array
-    a: int
-    b: int
+    kernel: Callable
+    inputs: tuple
+    static: dict
 
 
 def stage(cols, lo, hi, a: int, b: int) -> Staged:
@@ -59,14 +64,58 @@ def stage(cols, lo, hi, a: int, b: int) -> Staged:
     params[:K] = np.asarray(lo, np.int32).reshape(K, 1, 1)
     params[K:2 * K] = np.asarray(hi, np.int32).reshape(K, 1, 1)
     params[2 * K] = n
-    return Staged(jnp.asarray(cols.reshape(K, -1, LANES)),
-                  jnp.asarray(params), a, b)
+    return Staged(sum_product_pallas,
+                  (jnp.asarray(cols.reshape(K, -1, LANES)),
+                   jnp.asarray(params)), {"a": a, "b": b})
+
+
+def pack_column(pages: Sequence[tuple], width: int
+                ) -> tuple[np.ndarray, np.ndarray]:
+    """One column's bit-packed pages, each ``(payload, values, base)``, as
+    the packed kernel reads them: their ``width``-bit little-endian
+    bitstreams end to end in uint32 words, zero-padded to whole tiles of
+    TILE_N values, and the base of each row of ROW_N values. Every page but
+    the last must hold a multiple of ROW_N values, so that each starts a
+    row."""
+    n = sum(count for _, count, _ in pages)
+    tiles = padded_rows(n, TILE_N) // TILE_N
+    words = np.empty(tiles * TILE_GROUPS * width, np.uint32)
+    buf = words.view(np.uint8)
+    bases = np.zeros(tiles * SUBLANES, np.int32)
+    at = row = 0
+    for payload, count, base in pages:
+        nbytes = (count * width + 7) // 8
+        buf[at:at + nbytes] = np.frombuffer(payload, np.uint8, count=nbytes)
+        rows = -(-count // ROW_N)
+        bases[row:row + rows] = base
+        at, row = at + nbytes, row + rows
+    buf[at:] = 0
+    return words, bases
+
+
+def stage_packed(columns: Sequence[tuple[np.ndarray, np.ndarray]],
+                 widths: Sequence[int], n: int, lo, hi, a: int,
+                 b: int) -> Staged:
+    """Put ``pack_column``'s K columns of ``n`` values on the device: their
+    words in one put, and in one more each column's bounds ``lo``, ``hi``
+    [K], the row count and the columns' bases. A column whose bases are all
+    0 skips the add. Bounds must lie in int32."""
+    bases = np.stack([base for _, base in columns])
+    params = np.concatenate([np.asarray(lo, np.int32),
+                             np.asarray(hi, np.int32),
+                             np.asarray([n], np.int32), bases.ravel()])
+    words = np.concatenate([w for w, _ in columns]).view(np.int32)
+    return Staged(sum_product_pallas_packed,
+                  (jnp.asarray(words), jnp.asarray(params)),
+                  {"widths": tuple(int(w) for w in widths),
+                   "based": tuple(bool(x.any()) for x in bases),
+                   "a": a, "b": b})
 
 
 def launch(staged: Staged) -> jax.Array:
     """Dispatch the kernel; its partial sums may not be ready yet."""
-    return sum_product_pallas(staged.cols, staged.params, a=staged.a,
-                              b=staged.b, interpret=interpret())
+    return staged.kernel(*staged.inputs, **staged.static,
+                         interpret=interpret())
 
 
 def fetch(out: jax.Array) -> tuple[int, int]:
@@ -81,3 +130,11 @@ def sum_product(cols, lo, hi, a: int, b: int) -> tuple[int, int]:
     [K, N]: (sum of ``cols[a] * cols[b]`` over the rows inside every
     ``[lo, hi]``, their count). The caller checks ``exact_for``."""
     return fetch(launch(stage(cols, lo, hi, a, b)))
+
+
+def sum_product_packed(columns: Sequence[tuple[np.ndarray, np.ndarray]],
+                       widths: Sequence[int], n: int, lo, hi, a: int,
+                       b: int) -> tuple[int, int]:
+    """``sum_product`` over ``pack_column``'s columns of ``n`` values. The
+    caller checks ``exact_for`` with ``block=TILE_N``."""
+    return fetch(launch(stage_packed(columns, widths, n, lo, hi, a, b)))

@@ -1,7 +1,8 @@
 """The aggregate node and its fused filter-and-sum kernel: exact integer
 answers equal to the plain reference (``kernels/aggregate/ref.py``), through
-the kernel (interpret mode here) and through the host path, in process and
-over the socket."""
+the kernel (interpret mode here), over decoded columns and straight from
+bit-packed pages, and through the host path, in process and over the
+socket."""
 
 import os
 
@@ -9,8 +10,14 @@ import numpy as np
 import pytest
 
 from repro.core import BullionWriter, ColumnSpec, Compliance, delete_rows
-from repro.dataset import SumProduct, dataset, optimize
-from repro.kernels.aggregate import exact_for, sum_product, sum_product_ref
+from repro.core import integrity
+from repro.core.encodings import EncodeContext
+from repro.core.encodings.numeric import FOR, FixedBitWidth, bit_packed
+from repro.core.footer import read_footer
+from repro.dataset import SumProduct, clear_footer_cache, dataset, optimize
+from repro.kernels.aggregate import (exact_for, pack_column, sum_product,
+                                     sum_product_packed, sum_product_ref)
+from repro.kernels.aggregate.kernel import TILE_N
 from repro.obs import metrics
 from repro.scan import C
 from repro.serve import DatasetServer, ServeClient
@@ -33,10 +40,10 @@ def _table(rng, n):
             "score": rng.random(n).astype(np.float32)}
 
 
-def _write(path, table, rows_per_group):
+def _write(path, table, rows_per_group, page_rows=None):
     w = BullionWriter(path, [ColumnSpec(k, str(v.dtype))
                              for k, v in table.items()],
-                      rows_per_group=rows_per_group)
+                      rows_per_group=rows_per_group, page_rows=page_rows)
     w.write_table(table)
     w.close()
 
@@ -75,8 +82,19 @@ def _ref(table, pred):
                            0, 1)
 
 
+COUNTERS = ("kernel_calls", "packed_groups", "host_groups")
+
+
 def _count(name):
     return metrics.counter(name).value
+
+
+def _counted(fn):
+    """``fn()``, and how far it moved each aggregate counter."""
+    before = [_count(f"bullion.aggregate.{c}") for c in COUNTERS]
+    out = fn()
+    return out, {c: _count(f"bullion.aggregate.{c}") - b
+                 for c, b in zip(COUNTERS, before)}
 
 
 # ---------------------------------------------------------------------------
@@ -252,6 +270,189 @@ def test_aggregate_validates_its_factors(shards):
             ds.aggregate(sum_product=("price", "nope"))
         with pytest.raises(ValueError, match="head"):
             ds.head(5).aggregate(sum_product=FACTORS)
+
+
+# ---------------------------------------------------------------------------
+# the packed kernel: bit-packed pages unpacked on the device
+# ---------------------------------------------------------------------------
+
+# rows of each page: every page but the last whole rows of 4,096 values,
+# one spanning four of them, one three; two tiles in all
+PAGES = (16_384, 8192, 12_288, 3000)
+
+
+def _pages(values, encoding):
+    """``values`` written page by page with the store's own encoder, as
+    ``pack_column`` takes them."""
+    out, at = [], 0
+    for rows in PAGES:
+        part = bit_packed(encoding.encode(values[at:at + rows],
+                                          EncodeContext()))
+        out.append((part.payload, part.n, part.base))
+        at += rows
+    return out
+
+
+@pytest.mark.parametrize("width", range(1, 32))
+def test_packed_kernel_matches_reference_at_every_width(width):
+    """FOR pages of ``width`` bits whose bases lie at int32's two ends, at
+    zero and below it, filtered and multiplied by a 2-bit FixedBitWidth
+    column: bit for bit the reference's and the decoded kernel's answer."""
+    rng = np.random.default_rng(width)
+    span = (1 << width) - 1
+    bases = (INT32[0], 0, -12_345, INT32[1] - span)
+    parts = []
+    for base, rows in zip(bases, PAGES):
+        off = rng.integers(0, span + 1, rows)
+        off[:2] = (0, span)                 # each page needs all its bits
+        parts.append(base + off)
+    x = np.concatenate(parts).astype(np.int32)
+    y = rng.integers(0, 4, len(x)).astype(np.int32)
+    y[::4096] = 3
+    px, py = _pages(x, FOR()), _pages(y, FixedBitWidth())
+    assert {p[2] for p in px} == set(bases) and {p[2] for p in py} == {0}
+    cols = np.stack([x, y])
+    lo = [int(np.quantile(x, 0.1)), 1]
+    hi = [int(np.quantile(x, 0.8)), 3]
+    n = len(x)
+    assert exact_for(n, 1 << 31, 3, TILE_N) and exact_for(n, 1 << 31, 3)
+    want = sum_product_ref(cols, lo, hi, 0, 1)
+    got = sum_product_packed([pack_column(px, width), pack_column(py, 2)],
+                             [width, 2], n, lo, hi, 0, 1)
+    assert got == want == sum_product(cols, lo, hi, 0, 1)
+    assert 0 < want[1] < n
+
+
+@pytest.fixture(scope="module")
+def packed(tmp_path_factory):
+    """Two shards of 40,000 rows, no deletes, in groups of 16,384 rows and
+    pages of 4,096 (a ragged last page), plus a clustered ``key`` column
+    whose page zone maps select pages: every page the aggregates read is
+    bit-packed, whole, at one width a chunk."""
+    d = str(tmp_path_factory.mktemp("packed"))
+    rng = np.random.default_rng(16)
+    shards = []
+    for s in range(2):
+        t = _table(rng, 40_000)
+        t["key"] = np.arange(s * 40_000, (s + 1) * 40_000, dtype=np.int32)
+        _write(os.path.join(d, f"part-{s:03d}.bln"), t, 16_384, 4096)
+        shards.append(t)
+    return d, {k: np.concatenate([t[k] for t in shards]) for k in shards[0]}
+
+
+@pytest.mark.parametrize("name", sorted(PREDICATES))
+def test_packed_pages_answer_as_the_decoded_path(packed, name):
+    """Every group straight from its packed pages: the same (value, rows)
+    as the host path, which decodes, and the reference."""
+    d, table = packed
+    pred = PREDICATES[name]
+    with dataset(d) as ds:
+        if pred is not None:
+            ds = ds.where(pred)
+        got, moved = _counted(lambda: ds.aggregate(sum_product=FACTORS))
+        host = ds._with_kernel(False).aggregate(sum_product=FACTORS)
+        groups = len(ds.tasks())
+    assert tuple(got) == tuple(host) == _ref(table, pred)
+    assert groups == (0 if name == "empty" else 6)
+    calls = 0 if name == "contradiction" else groups    # an empty interval
+    assert moved == {"kernel_calls": calls, "packed_groups": calls,
+                     "host_groups": 0}
+
+
+def test_packed_pages_of_a_partial_page_selection(packed):
+    """Page zone maps on the clustered key leave some of a group's pages;
+    the kernel reads those alone."""
+    d, table = packed
+    pred = (C("key") >= 5000) & (C("key") < 30_000) & (C("qty") < 24)
+    with dataset(d) as ds:
+        ds = ds.where(pred)
+        tasks = ds.tasks()
+        got, moved = _counted(lambda: ds.aggregate(sum_product=FACTORS))
+    assert [t.pages for t in tasks] == [(1, 2, 3), None]
+    assert tuple(got) == _ref(table, pred)
+    assert moved == {"kernel_calls": 2, "packed_groups": 2, "host_groups": 0}
+
+
+def _flip(path, page):
+    fv, _ = read_footer(path)
+    off, size = fv.page_extent(page)
+    with open(path, "r+b") as f:
+        f.seek(off + size // 2)
+        b = f.read(1)
+        f.seek(off + size // 2)
+        f.write(bytes([b[0] ^ 0xFF]))
+    clear_footer_cache()
+
+
+def _first_page(path, column):
+    fv, _ = read_footer(path)
+    return fv.chunk_pages(0, fv.column_index(column))[0]
+
+
+def _narrow_first_page(t):
+    """The first page's quantities fit 5 bits, the chunk's others need 6."""
+    t["qty"][:4096] %= 31
+
+
+def _constant_group(t):
+    """One discount in all of group 0: Constant pages, not bit-packed."""
+    t["disc"][:8192] = 6
+
+
+# each breaks the packed path for group 0 of two: (table edit, file edit)
+FALLBACKS = {
+    "mixed_widths": (_narrow_first_page, None),
+    "deleted_row": (None, lambda p: delete_rows(p, np.array([5]),
+                                                level=Compliance.LEVEL1)),
+    "quarantined_page": (None, lambda p: _flip(p, _first_page(p, "qty"))),
+    "not_bit_packed": (_constant_group, None),
+    "pinned_rows": (None, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FALLBACKS))
+def test_fallback_groups_answer_as_today(tmp_path, case):
+    """A group the packed path refuses is decoded, as before this path
+    existed: the same answer as the host path, the same kernel calls, and
+    only the other group counted as packed."""
+    rng = np.random.default_rng(17)
+    t = _table(rng, 16_384)
+    edit_table, edit_file = FALLBACKS[case]
+    if edit_table:
+        edit_table(t)
+    path = str(tmp_path / "t.bln")
+    _write(path, t, 8192, 4096)
+    if edit_file:
+        edit_file(path)
+    pred = (C("ship") >= 8766) & (C("disc") >= 5) & (C("qty") < 24)
+    if case == "quarantined_page":
+        integrity.set_verify_policy("full")
+        integrity.set_corruption_policy("mask")
+    try:
+        with dataset(path) as ds:
+            ds = ds.where(pred)
+            if case == "pinned_rows":
+                ds = ds.with_rows(np.arange(0, 16_384, 3))
+            got, moved = _counted(lambda: ds.aggregate(sum_product=FACTORS))
+            host = ds._with_kernel(False).aggregate(sum_product=FACTORS)
+    finally:
+        integrity.set_verify_policy(None)
+        integrity.set_corruption_policy(None)
+        integrity.QUARANTINE.clear()
+        clear_footer_cache()
+    assert tuple(got) == tuple(host)
+    if case == "pinned_rows":
+        keep = np.zeros(16_384, bool)
+        keep[::3] = True
+        assert tuple(got) == _ref({k: v[keep] for k, v in t.items()}, pred)
+        assert moved == {"kernel_calls": 0, "packed_groups": 0,
+                         "host_groups": 2}
+        return
+    if case == "deleted_row":
+        t = {k: np.delete(v, 5) for k, v in t.items()}
+    if case != "quarantined_page":      # masked rows read 0 on both paths
+        assert tuple(got) == _ref(t, pred)
+    assert moved == {"kernel_calls": 2, "packed_groups": 1, "host_groups": 0}
 
 
 # ---------------------------------------------------------------------------

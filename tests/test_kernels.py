@@ -5,30 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.bitunpack import bitunpack, bitunpack_ref, pack_bp32
 from repro.kernels.dequant import dequant, dequant_ref
 from repro.kernels.filter import range_mask, range_mask_ref
 from repro.kernels.flash_attention import attention_ref, flash_attention
-
-
-@pytest.mark.parametrize("width", [1, 2, 3, 5, 8, 13, 16, 24, 31, 32])
-def test_bitunpack_widths(width):
-    rng = np.random.default_rng(width)
-    n = 32 * 256
-    hi = (1 << width) - 1 if width < 32 else 0xFFFFFFFF
-    vals = (rng.integers(0, 1 << 31, n) & hi).astype(np.uint32)
-    planes = pack_bp32(vals, width)
-    out = np.asarray(bitunpack(planes, width, n_values=n))
-    assert np.array_equal(out, vals)
-    assert np.array_equal(bitunpack_ref(planes, width)[:n], vals)
-
-
-def test_bitunpack_ragged_length():
-    rng = np.random.default_rng(0)
-    n = 32 * 256 + 7 * 32  # not a multiple of the block
-    vals = rng.integers(0, 1 << 11, n).astype(np.uint32)
-    out = np.asarray(bitunpack(pack_bp32(vals, 11), 11, n_values=n))
-    assert np.array_equal(out, vals)
 
 
 @pytest.mark.parametrize("n_cols,n", [(1, 2048), (3, 4096), (5, 2048 + 777)])

@@ -15,8 +15,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.aggregate.kernel import BLOCK_ROWS, sum_product_pallas
-from repro.kernels.bitunpack.kernel import bitunpack_pallas
+from repro.kernels.aggregate.kernel import (BLOCK_ROWS, TILE_GROUPS, TILE_N,
+                                           sum_product_pallas,
+                                           sum_product_pallas_packed)
 from repro.kernels.dequant.kernel import dequant_pallas
 from repro.kernels.filter.kernel import range_mask_pallas
 
@@ -73,12 +74,6 @@ def test_dequant_compiles_for_v5e(one_chip, q_dtype):
     assert "tpu_custom_call" in text
 
 
-def test_bitunpack_compiles_for_v5e(one_chip):
-    fn = jax.jit(lambda p: bitunpack_pallas(p, 7, interpret=False))
-    text = _compile_text(fn, ((2048, 7), jnp.uint32), sharding=one_chip)
-    assert "tpu_custom_call" in text
-
-
 @pytest.mark.parametrize("n_cols", [2, 4])
 def test_sum_product_compiles_for_v5e(one_chip, n_cols):
     """The fused filter-and-sum kernel at one row group: TPC-H Q6 reads
@@ -93,3 +88,22 @@ def test_sum_product_compiles_for_v5e(one_chip, n_cols):
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
     assert "HloModule jit_sum_product_pallas" in text
+
+
+def test_sum_product_packed_compiles_for_v5e(one_chip):
+    """The packed form at one row group of TPC-H Q6's pages: quantity,
+    price and discount bit-packed at 6, 24 and 4 bits, the ship date
+    frame-of-reference at 12 bits with a base a row."""
+    widths = (12, 4, 6, 24)
+    tiles = N_ROWS // TILE_N
+    rows = tiles * 8
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((tiles * TILE_GROUPS * sum(widths),), jnp.int32),
+        ((2 * len(widths) + 1 + len(widths) * rows,), jnp.int32))]
+    lowered = sum_product_pallas_packed.lower(
+        *args, widths=widths, based=(True, False, False, False), a=3, b=1,
+        interpret=False)
+    assert 'kernel_name = "sum_product_packed"' in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "HloModule jit_sum_product_pallas_packed" in text
