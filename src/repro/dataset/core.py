@@ -13,7 +13,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from dataclasses import dataclass, field
-from typing import Iterator, NamedTuple, Optional, Sequence
+from typing import Callable, Iterator, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -323,47 +323,28 @@ class Dataset:
                       ) -> Iterator[tuple[ScanTask, executor.GroupResult]]:
         """Run the plan; ``output_columns`` overrides materialization for
         data-free terminals (row_ids/count) without spawning a new instance
-        (caches and the pruned-bytes credit stay shared). ``parallelism > 1``
-        decodes independent (shard, group) tasks on a bounded thread pool;
-        ``io_depth > 1`` prefetches upcoming tasks' coalesced byte ranges on
-        the I/O scheduler so preads overlap decode (``io_depth=1`` is the
-        serial per-group read path). Results stream in task order either
-        way, so the output is identical to a serial run."""
+        (caches and the pruned-bytes credit stay shared). Results stream in
+        task order (see ``_run``), so the output is identical to a serial
+        run."""
         opt = self.plan()
-        phys = self.physical_plan()
-        self._credit(phys)
         p = opt.logical
         cols = opt.output_columns if output_columns is None \
             else tuple(output_columns)
         filtered = p.predicate is not None or p.row_ids is not None
 
-        if io_depth < 1:
-            raise ValueError(f"io_depth must be >= 1, got {io_depth}")
+        def group(reader, task: ScanTask) -> Optional[executor.GroupResult]:
+            return executor.execute_group(
+                reader, task.group, columns=cols, predicate=p.predicate,
+                rows=task.rows, drop_deleted=p.drop_deleted,
+                dequant=p.dequantize, use_kernel=p.use_kernel,
+                pages=task.pages)
+
+        results = self._run(group, opt.prefetch_columns(cols), parallelism,
+                            io_depth)
         emitted, limit = 0, p.limit
         if limit is not None and limit <= 0:
             return
-        sched = None
-        prefetch_cols = opt.prefetch_columns(cols)
-        if io_depth > 1 and len(phys.tasks) > 1 and prefetch_cols:
-            from .io import IOScheduler
-            sched = IOScheduler(self._source, phys.tasks,
-                                columns=prefetch_cols, io_depth=io_depth)
-
-        def run(item) -> Optional[executor.GroupResult]:
-            i, task = item
-            with _trace.span("exec.task", cat="exec",
-                             shard=task.shard, group=task.group):
-                reader = sched.reader_for(i) if sched is not None \
-                    else self._source.reader(task.shard)
-                return executor.execute_group(
-                    reader, task.group,
-                    columns=cols, predicate=p.predicate,
-                    rows=task.rows, drop_deleted=p.drop_deleted,
-                    dequant=p.dequantize, use_kernel=p.use_kernel,
-                    pages=task.pages)
-
-        for (_, task), res in executor.run_tasks(
-                list(enumerate(phys.tasks)), run, parallelism, io=sched):
+        for task, res in results:
             if res is None or (filtered and not len(res.row_ids)):
                 continue
             if limit is not None and emitted + len(res.row_ids) > limit:
@@ -372,6 +353,38 @@ class Dataset:
             yield task, res
             if limit is not None and emitted >= limit:
                 break
+
+    def _run(self, group: Callable, prefetch_cols: Sequence[str],
+             parallelism: int, io_depth: int) -> Iterator[tuple]:
+        """The one task loop of the terminals: credit the plan's pruned
+        bytes, then run ``group(reader, task)`` for every task under an
+        ``exec.task`` span, yielding ``(task, result)`` in task order.
+        ``parallelism > 1`` runs independent (shard, group) tasks on a
+        bounded thread pool; ``io_depth > 1`` prefetches upcoming tasks'
+        coalesced byte ranges of ``prefetch_cols`` on the I/O scheduler so
+        preads overlap decode (``io_depth=1`` is the serial per-group read
+        path). Planning and the checks happen here, before the first task
+        runs."""
+        phys = self.physical_plan()
+        self._credit(phys)
+        if io_depth < 1:
+            raise ValueError(f"io_depth must be >= 1, got {io_depth}")
+        sched = None
+        if io_depth > 1 and len(phys.tasks) > 1 and prefetch_cols:
+            from .io import IOScheduler
+            sched = IOScheduler(self._source, phys.tasks,
+                                columns=prefetch_cols, io_depth=io_depth)
+
+        def run(item):
+            i, task = item
+            with _trace.span("exec.task", cat="exec",
+                             shard=task.shard, group=task.group):
+                reader = sched.reader_for(i) if sched is not None \
+                    else self._source.reader(task.shard)
+                return group(reader, task)
+
+        return ((task, res) for (_, task), res in executor.run_tasks(
+            list(enumerate(phys.tasks)), run, parallelism, io=sched))
 
     def _page_sel(self, shard: int, group: int) -> Optional[tuple]:
         """Surviving page ordinals the lowered plan picked for (shard,
@@ -519,33 +532,18 @@ class Dataset:
 
     def _aggregate(self, parallelism: int, io_depth: int) -> AggregateResult:
         opt = self.plan()
-        phys = self.physical_plan()
-        self._credit(phys)
         p = opt.logical
-        if io_depth < 1:
-            raise ValueError(f"io_depth must be >= 1, got {io_depth}")
-        sched = None
-        if io_depth > 1 and len(phys.tasks) > 1:
-            from .io import IOScheduler
-            sched = IOScheduler(self._source, phys.tasks,
-                                columns=opt.prefetch_columns(),
-                                io_depth=io_depth)
 
-        def run(item) -> tuple[int, int]:
-            i, task = item
-            with _trace.span("exec.task", cat="exec",
-                             shard=task.shard, group=task.group):
-                reader = sched.reader_for(i) if sched is not None \
-                    else self._source.reader(task.shard)
-                return executor.aggregate_group(
-                    reader, task.group, factors=p.aggregate.columns(),
-                    predicate=p.predicate, rows=task.rows,
-                    drop_deleted=p.drop_deleted, use_kernel=p.use_kernel,
-                    pages=task.pages)
+        def group(reader, task: ScanTask) -> tuple[int, int]:
+            return executor.aggregate_group(
+                reader, task.group, factors=p.aggregate.columns(),
+                predicate=p.predicate, rows=task.rows,
+                drop_deleted=p.drop_deleted, use_kernel=p.use_kernel,
+                pages=task.pages)
 
         value = rows = 0
-        for _, (v, n) in executor.run_tasks(
-                list(enumerate(phys.tasks)), run, parallelism, io=sched):
+        for _, (v, n) in self._run(group, opt.prefetch_columns(),
+                                   parallelism, io_depth):
             value += v
             rows += n
         return AggregateResult(value, rows)

@@ -24,7 +24,7 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import dataclass, field
-from typing import TYPE_CHECKING, Callable, NamedTuple, Optional, Sequence
+from typing import TYPE_CHECKING, Callable, Optional, Sequence
 
 import numpy as np
 
@@ -40,6 +40,7 @@ from ..scan.predicate import Predicate, conjunctive_ranges, evaluate
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard
     from ..core.reader import BullionReader
+    from ..kernels.aggregate.ops import Call
 
 
 @dataclass
@@ -291,6 +292,25 @@ def _f32_shrink(lo: float, hi: float) -> tuple[np.float32, np.float32]:
     return lo32, hi32
 
 
+def _round_trip(layer: str, stage: Callable, fetch: Callable):
+    """One device round trip of a kernel, its steps timed apart: host
+    staging and the puts (``stage()``, which answers a ``kernels.Staged``),
+    the jitted call's dispatch, and the wait for the output and its copy
+    back (``fetch(out)``), under ``<layer>.stage``, ``.launch`` and
+    ``.fetch``. Counts ``bullion.<layer>.kernel_calls``."""
+    from ..kernels import launch
+    with _trace.span(f"{layer}.stage", cat=layer):
+        _metrics.counter(f"bullion.{layer}.kernel_calls").inc()
+        staged = stage()
+    with _trace.span(f"{layer}.launch", cat=layer):
+        out = launch(staged)
+    with _trace.span(f"{layer}.fetch", cat=layer):
+        # drop the inputs' device arrays before the wait for the output;
+        # inside the span, so that launch and fetch abut
+        del staged
+        return fetch(out)
+
+
 def eval_mask(pred: Predicate, tbl: dict,
               use_kernel: Optional[bool]) -> np.ndarray:
     """Predicate -> row mask; Pallas kernel when the predicate compiles
@@ -309,31 +329,22 @@ def eval_mask(pred: Predicate, tbl: dict,
     if not use_kernel:
         return evaluate(pred, tbl)
     from ..kernels.filter import ops
-    # the device round trip, split: host staging and the puts, the jitted
-    # call's dispatch, and the wait for the mask and its copy back
-    with _trace.span("filter.stage", cat="filter"):
-        names = list(ranges)
+    names = list(ranges)
+
+    def stage():
         bounds = [_f32_shrink(*ranges[c]) for c in names]
-        cols = np.stack([np.asarray(tbl[c], np.float32) for c in names])
-        _metrics.counter("bullion.filter.kernel_calls").inc()
-        staged = ops.stage(cols,
-                           np.asarray([b[0] for b in bounds], np.float32),
-                           np.asarray([b[1] for b in bounds], np.float32))
-    with _trace.span("filter.launch", cat="filter"):
-        out = ops.launch(staged)
-    with _trace.span("filter.fetch", cat="filter"):
-        # drop the inputs' device arrays before the wait for the mask;
-        # inside the span, so that launch and fetch abut
-        n_values = staged.n_values
-        del staged
-        return ops.fetch(out, n_values)
+        return ops.stage(np.stack([np.asarray(tbl[c], np.float32)
+                                   for c in names]),
+                         np.asarray([b[0] for b in bounds], np.float32),
+                         np.asarray([b[1] for b in bounds], np.float32))
+
+    n = len(tbl[names[0]])
+    return _round_trip("filter", stage, lambda out: ops.fetch(out, n))
 
 
 # ---------------------------------------------------------------------------
 # partial aggregates (NumPy or the fused Pallas filter-and-sum kernel)
 # ---------------------------------------------------------------------------
-
-_INT32 = (-(1 << 31), (1 << 31) - 1)
 
 
 def _int32_safe(dt) -> bool:
@@ -371,63 +382,34 @@ def _host_sum_product(x: np.ndarray, y: np.ndarray) -> int:
     return (int((p >> 32).sum()) << 32) + int((p & 0xFFFFFFFF).sum())
 
 
-class _KernelCall(NamedTuple):
-    """The fused kernel's columns, their closed int32 intervals, and the
-    factors' positions among them (``a`` is split into limbs)."""
-
-    names: list
-    lo: list
-    hi: list
-    a: int
-    b: int
-
-
 def _kernel_call(pred: Optional[Predicate], dtypes: dict, n: int,
                  factors: Sequence[str], bounds: Optional[Sequence[int]],
-                 block: int) -> Optional[_KernelCall]:
-    """The fused kernel's call over ``n`` rows whose columns have
-    ``dtypes``, when the predicate is a conjunction of ranges, every column
-    it reads is an integer column that fits int32, and the factors'
-    magnitudes ``bounds`` keep its lane sums exact in programs of ``block``
-    rows; None where only NumPy is exact."""
+                 packed: bool) -> Optional["Call"]:
+    """The fused kernel's call (``kernels.aggregate.ops.plan``) over ``n``
+    rows whose columns have ``dtypes``, when the predicate is a conjunction
+    of ranges, every column it reads is an integer column that fits int32,
+    and the factors' magnitudes ``bounds`` keep the plain or the ``packed``
+    kernel's lane sums exact; None where only NumPy is exact."""
     from ..kernels.aggregate import ops as kernel_ops
-    a, b = factors
     cols = pred.columns() if pred is not None else set()
     if bounds is None or not all(_int32_safe(dtypes[c])
-                                 for c in {*cols, a, b}):
+                                 for c in {*cols, *factors}):
         return None
     ranges = conjunctive_ranges(pred, int_columns=cols) \
         if pred is not None else {}
     if ranges is None:
         return None
-    if kernel_ops.exact_for(n, bounds[0], bounds[1], block):
-        order = (a, b)
-    elif kernel_ops.exact_for(n, bounds[1], bounds[0], block):
-        order = (b, a)
-    else:
-        return None
-    names = list(dict.fromkeys([*ranges, *order]))
-    return _KernelCall(
-        names, [max(ranges.get(c, _INT32)[0], _INT32[0]) for c in names],
-        [min(ranges.get(c, _INT32)[1], _INT32[1]) for c in names],
-        names.index(order[0]), names.index(order[1]))
+    return kernel_ops.plan(ranges, factors, bounds, n, packed)
 
 
-def _run_kernel(call: _KernelCall, stage: Callable) -> tuple[int, int]:
-    """One round trip of the fused kernel, its steps timed apart:
-    ``stage()`` lays out and puts the call's columns."""
+def _kernel_sum_product(call: "Call", stage: Callable) -> tuple[int, int]:
+    """The fused kernel's answer to ``call``: ``stage()`` lays out and puts
+    the call's columns."""
     from ..kernels.aggregate import ops as kernel_ops
     # an interval outside int32 admits no int32 value
     if any(lo > hi for lo, hi in zip(call.lo, call.hi)):
         return 0, 0
-    with _trace.span("aggregate.stage", cat="aggregate"):
-        _metrics.counter("bullion.aggregate.kernel_calls").inc()
-        staged = stage()
-    with _trace.span("aggregate.launch", cat="aggregate"):
-        out = kernel_ops.launch(staged)
-    with _trace.span("aggregate.fetch", cat="aggregate"):
-        del staged
-        return kernel_ops.fetch(out)
+    return _round_trip("aggregate", stage, kernel_ops.fetch)
 
 
 def eval_sum_product(pred: Optional[Predicate], tbl: dict,
@@ -446,7 +428,7 @@ def eval_sum_product(pred: Optional[Predicate], tbl: dict,
     n = len(tbl[a])
     call = None if rows_mask is not None else _kernel_call(
         pred, {c: getattr(v, "dtype", None) for c, v in tbl.items()}, n,
-        factors, bounds, kernel_ops.BLOCK_N)
+        factors, bounds, packed=False)
     if use_kernel and call is None:
         raise ValueError(
             "the aggregate kernel requires a conjunctive range predicate "
@@ -463,39 +445,24 @@ def eval_sum_product(pred: Optional[Predicate], tbl: dict,
         return (_host_sum_product(np.asarray(tbl[a])[mask],
                                   np.asarray(tbl[b])[mask]),
                 int(mask.sum()))
-    return _run_kernel(call, lambda: kernel_ops.stage(
+    return _kernel_sum_product(call, lambda: kernel_ops.stage(
         np.stack([np.asarray(tbl[c], np.int32) for c in call.names]),
         call.lo, call.hi, call.a, call.b))
 
 
-def _packed_pages(parts: list, page_rows: list, dtype: np.dtype) -> bool:
-    """Are these one column's pages, as ``bit_packed`` read them, whole
-    pages the packed kernel can take: bit-packed at one width of 1 to 31
-    bits over the column's dtype, each holding its page's rows, all but the
-    last a whole number of the kernel's rows, and every base an int32?"""
-    from ..kernels.aggregate.kernel import ROW_N
-    if any(p is None for p in parts):
-        return False
-    width = parts[0].width
-    return (1 <= width <= 31
-            and all(p.width == width and p.dtype == dtype and p.n == rows
-                    and _INT32[0] <= p.base <= _INT32[1]
-                    for p, rows in zip(parts, page_rows))
-            and all(p.n % ROW_N == 0 for p in parts[:-1]))
-
-
-def _packed_sum_product(reader: "BullionReader", group: int,
-                        names: Sequence[str], raw: dict, *,
-                        predicate: Optional[Predicate],
-                        factors: Sequence[str],
-                        bounds: Optional[Sequence[int]],
-                        pages: Optional[Sequence[int]]
-                        ) -> Optional[tuple[int, int]]:
+def _packed_call(reader: "BullionReader", group: int, names: Sequence[str],
+                 raw: dict, *, predicate: Optional[Predicate],
+                 factors: Sequence[str], bounds: Optional[Sequence[int]],
+                 pages: Optional[Sequence[int]]
+                 ) -> Optional[tuple[int, Callable]]:
     """The group's partial aggregate straight from the packed words of its
     pages of ``names`` (read into ``raw``), unpacked by the kernel: the
-    host joins payloads and decodes nothing. None where a page the kernel would read is not a whole bit-packed page
-    of a plain int32-safe column, carries a deletion vector or was
-    quarantined, or where only NumPy is exact: the group is then decoded."""
+    host joins payloads and decodes nothing. Answers the group's row count
+    and the call that runs the kernel. None where a page the kernel would
+    read is not a whole bit-packed page of a plain int32-safe column at one
+    width, carries a deletion vector or was quarantined, where the kernel
+    cannot take the pages, or where only NumPy is exact: the group is then
+    decoded."""
     from ..kernels.aggregate import ops as kernel_ops
     fv = reader.footer
     cols = {c: fv.column_index(c) for c in names}
@@ -504,8 +471,7 @@ def _packed_sum_product(reader: "BullionReader", group: int,
     page_rows = fv.arr(Sec.PAGE_ROWS, np.uint32)
     pids = {c: _chunk_page_ids(fv, group, i, pages) for c, i in cols.items()}
     n = sum(int(page_rows[p]) for p in pids[factors[0]])
-    call = _kernel_call(predicate, dtypes, n, factors, bounds,
-                        kernel_ops.TILE_N)
+    call = _kernel_call(predicate, dtypes, n, factors, bounds, packed=True)
     kinds = fv.arr(Sec.COL_KIND, np.uint8)
     if call is None or any(
             kinds[cols[c]] != ColKind.SCALAR
@@ -523,24 +489,23 @@ def _packed_sum_product(reader: "BullionReader", group: int,
             parts = [bit_packed(raw[p])
                      if int(flags[p]) & 0x7F == PageType.SCALAR else None
                      for p in ps]
-            if not _packed_pages(parts, [int(page_rows[p]) for p in ps],
-                                 dtypes[c]):
+            if any(p is None or p.width != parts[0].width
+                   or p.dtype != dtypes[c] or p.n != int(page_rows[pid])
+                   for p, pid in zip(parts, ps)):
+                return None
+            column = kernel_ops.pack_column(
+                [(p.payload, p.n, p.base) for p in parts], parts[0].width)
+            if column is None:
                 return None
             widths.append(parts[0].width)
-            columns.append(kernel_ops.pack_column(
-                [(p.payload, p.n, p.base) for p in parts], parts[0].width))
+            columns.append(column)
 
     def stage():
         _metrics.counter("bullion.aggregate.packed_groups").inc()
         return kernel_ops.stage_packed(columns, widths, n, call.lo, call.hi,
                                        call.a, call.b)
 
-    sp = _trace.span("exec.aggregate", cat="exec", group=group)
-    with sp:
-        value, count = _run_kernel(call, stage)
-        if sp.enabled:
-            sp.set(rows_in=n, rows_out=count)
-    return value, count
+    return n, functools.partial(_kernel_sum_product, call, stage)
 
 
 # ---------------------------------------------------------------------------
@@ -777,25 +742,26 @@ def _aggregate_group_once(reader: "BullionReader", group: int, *,
     names = list(dict.fromkeys([*pred_cols, *factors]))
     raw = read_pages(reader, names, group, pages)
     bounds = factor_bounds(fv, group, factors)
+    trip = None
     if rows is None and use_kernel is not False:
-        packed = _packed_sum_product(reader, group, names, raw,
-                                     predicate=predicate, factors=factors,
-                                     bounds=bounds, pages=pages)
-        if packed is not None:
-            return packed
-    tbl = decode_pages(reader, names, group, raw, drop_deleted=drop_deleted,
-                       dequant=True, pages=pages, align_raw=not drop_deleted,
-                       masked_out=masked_out)
-    rows_mask = None
-    if rows is not None:
-        rows_mask = _rows_mask(rows,
-                               *_row_space(fv, group, pages, drop_deleted))
+        trip = _packed_call(reader, group, names, raw, predicate=predicate,
+                            factors=factors, bounds=bounds, pages=pages)
+    if trip is None:
+        tbl = decode_pages(reader, names, group, raw,
+                           drop_deleted=drop_deleted, dequant=True,
+                           pages=pages, align_raw=not drop_deleted,
+                           masked_out=masked_out)
+        rows_mask = None if rows is None else _rows_mask(
+            rows, *_row_space(fv, group, pages, drop_deleted))
+        trip = len(tbl[factors[0]]), functools.partial(
+            eval_sum_product, predicate, tbl, factors, bounds, use_kernel,
+            rows_mask)
+    n, run = trip
     sp = _trace.span("exec.aggregate", cat="exec", group=group)
     with sp:
-        value, count = eval_sum_product(predicate, tbl, factors, bounds,
-                                        use_kernel, rows_mask)
+        value, count = run()
         if sp.enabled:
-            sp.set(rows_in=len(tbl[factors[0]]), rows_out=count)
+            sp.set(rows_in=n, rows_out=count)
     return value, count
 
 

@@ -3,26 +3,28 @@ bit-packed pages, runs the kernel, and adds its per-lane partial sums
 exactly on the host.
 
 ``sum_product`` and ``sum_product_packed`` are whole device round trips;
-``stage`` or ``stage_packed``, ``launch`` and ``fetch`` are their steps, for
-a caller that times them apart. ``pack_column`` lays one column's pages out
-as the packed kernel reads them. ``exact_for`` says whether a call's int32
-accumulators are exact for given factor magnitudes.
+``stage`` or ``stage_packed``, ``launch`` (the package's) and ``fetch`` are
+their steps, for a caller that times them apart. ``plan`` lays out a call
+whose int32 lane sums are exact, and ``pack_column`` one column's pages as
+the packed kernel reads them; each answers None where the kernel cannot
+take its input. ``exact_for`` says whether a call's int32 accumulators are
+exact for given factor magnitudes.
 """
 
 from __future__ import annotations
 
-from typing import Callable, NamedTuple, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import interpret
+from .. import Staged, launch
 from .kernel import (BLOCK_N, LANES, LIMB_BITS, LIMB_MASK, ROW_N, SUBLANES,
                      TILE_GROUPS, TILE_N, sum_product_pallas,
                      sum_product_pallas_packed)
 
-INT32_MAX = (1 << 31) - 1
+INT32 = (-(1 << 31), (1 << 31) - 1)
 
 
 def padded_rows(n: int, block: int = BLOCK_N) -> int:
@@ -38,16 +40,40 @@ def exact_for(n: int, max_abs_a: int, max_abs_b: int,
     below 2**12, the high limb at most ``(max_abs_a >> 12) + 1``."""
     per_lane = padded_rows(n, block) // (SUBLANES * LANES)
     limb = max(LIMB_MASK, (int(max_abs_a) >> LIMB_BITS) + 1)
-    return per_lane * limb * int(max_abs_b) <= INT32_MAX
+    return per_lane * limb * int(max_abs_b) <= INT32[1]
 
 
-class Staged(NamedTuple):
-    """A call's kernel, its inputs on the device, and its static
-    arguments (which of its columns multiply, and how they are packed)."""
+class Call(NamedTuple):
+    """A call's columns in the order the kernel reads them, their closed
+    int32 intervals, and the factors' positions among them (``a`` is split
+    into limbs)."""
 
-    kernel: Callable
-    inputs: tuple
-    static: dict
+    names: list
+    lo: list
+    hi: list
+    a: int
+    b: int
+
+
+def plan(ranges: dict, factors: Sequence[str], bounds: Sequence[int],
+         n: int, packed: bool) -> Optional[Call]:
+    """The call that sums ``factors[0] * factors[1]`` over the ``n`` rows
+    inside every closed interval of ``ranges`` (column -> (lo, hi)), the
+    factors at most ``bounds`` in magnitude: each interval clipped to int32,
+    and the factor whose limbs keep the lane sums exact, in the programs of
+    the plain or the ``packed`` kernel, split. None where neither is."""
+    block = TILE_N if packed else BLOCK_N
+    a, b = factors
+    if exact_for(n, bounds[0], bounds[1], block):
+        order = (a, b)
+    elif exact_for(n, bounds[1], bounds[0], block):
+        order = (b, a)
+    else:
+        return None
+    names = list(dict.fromkeys([*ranges, *order]))
+    return Call(names, [max(ranges.get(c, INT32)[0], INT32[0]) for c in names],
+                [min(ranges.get(c, INT32)[1], INT32[1]) for c in names],
+                names.index(order[0]), names.index(order[1]))
 
 
 def stage(cols, lo, hi, a: int, b: int) -> Staged:
@@ -70,13 +96,18 @@ def stage(cols, lo, hi, a: int, b: int) -> Staged:
 
 
 def pack_column(pages: Sequence[tuple], width: int
-                ) -> tuple[np.ndarray, np.ndarray]:
+                ) -> Optional[tuple[np.ndarray, np.ndarray]]:
     """One column's bit-packed pages, each ``(payload, values, base)``, as
     the packed kernel reads them: their ``width``-bit little-endian
     bitstreams end to end in uint32 words, zero-padded to whole tiles of
-    TILE_N values, and the base of each row of ROW_N values. Every page but
-    the last must hold a multiple of ROW_N values, so that each starts a
-    row."""
+    TILE_N values, and the base of each row of ROW_N values. None where the
+    kernel cannot take them: ``width`` is not 1 to 31 bits, a page but the
+    last does not hold a multiple of ROW_N values (each must start a row),
+    or a base is not an int32."""
+    if not 1 <= width <= 31 or any(
+            not INT32[0] <= base <= INT32[1] for _, _, base in pages) \
+            or any(count % ROW_N for _, count, _ in pages[:-1]):
+        return None
     n = sum(count for _, count, _ in pages)
     tiles = padded_rows(n, TILE_N) // TILE_N
     words = np.empty(tiles * TILE_GROUPS * width, np.uint32)
@@ -110,12 +141,6 @@ def stage_packed(columns: Sequence[tuple[np.ndarray, np.ndarray]],
                   {"widths": tuple(int(w) for w in widths),
                    "based": tuple(bool(x.any()) for x in bases),
                    "a": a, "b": b})
-
-
-def launch(staged: Staged) -> jax.Array:
-    """Dispatch the kernel; its partial sums may not be ready yet."""
-    return staged.kernel(*staged.inputs, **staged.static,
-                         interpret=interpret())
 
 
 def fetch(out: jax.Array) -> tuple[int, int]:

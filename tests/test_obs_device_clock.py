@@ -13,7 +13,7 @@ import pytest
 
 from repro.core import BullionWriter, ColumnSpec
 from repro.dataset import clear_footer_cache, dataset
-from repro.obs import querylog, trace
+from repro.obs import metrics, querylog, trace
 from repro.scan import C
 from repro.serve import DatasetServer, ServeClient
 
@@ -105,25 +105,80 @@ def test_served_scan_with_tracing_off_allocates_no_spans(table):
         assert trace.allocations() == before
 
 
-def test_filter_round_trip_split_covers_the_kernel_path(table):
-    with dataset(table) as ds:
+def _lineitem(path: str, mixed_widths: bool) -> str:
+    """Two groups of 8,192 int32 rows in pages of 4,096, every page
+    bit-packed. With ``mixed_widths`` each group's first page of ``qty``
+    fits 5 bits and its other needs 6: the packed path refuses the group
+    and the kernel reads it decoded."""
+    rng = np.random.default_rng(17)
+    n = 16_384
+    qty = rng.integers(1, 51, n).astype(np.int32)
+    if mixed_widths:
+        for g in range(0, n, 8192):
+            qty[g:g + 4096] %= 31
+    w = BullionWriter(path, [ColumnSpec(c, "int32")
+                             for c in ("ship", "disc", "qty", "price")],
+                      rows_per_group=8192, page_rows=4096)
+    w.write_table({"ship": rng.integers(8036, 10_592, n).astype(np.int32),
+                   "disc": rng.integers(0, 11, n).astype(np.int32),
+                   "qty": qty,
+                   "price": qty * rng.integers(900, 2100, n).astype(np.int32)})
+    w.close()
+    return path
+
+
+Q6 = (C("ship") >= 8766) & (C("disc") >= 5) & (C("qty") < 24)
+
+# path -> (kernel layer, enclosing span, groups, mixed widths)
+ROUND_TRIPS = {
+    "filter": ("filter", "exec.filter", N_ROWS // 1024, None),
+    "aggregate_decoded": ("aggregate", "exec.aggregate", 2, True),
+    "aggregate_packed": ("aggregate", "exec.aggregate", 2, False),
+}
+COUNTERS = ("bullion.filter.kernel_calls", "bullion.aggregate.kernel_calls",
+            "bullion.aggregate.packed_groups", "bullion.aggregate.host_groups")
+
+
+@pytest.mark.parametrize("path", sorted(ROUND_TRIPS))
+def test_filter_round_trip_split_covers_the_kernel_path(table, tmp_path,
+                                                        path):
+    layer, outer, groups, mixed_widths = ROUND_TRIPS[path]
+    if mixed_widths is None:
+        src = table
+
+        def query(ds):
+            return len(ds.where(SCAN).select(["id"]).to_table()["id"])
+    else:
+        src = _lineitem(str(tmp_path / "lineitem.bln"), mixed_widths)
+
+        def query(ds):
+            return ds.where(Q6).aggregate(sum_product=("price", "disc")).rows
+    with dataset(src) as ds:
         # the first call imports the kernel and compiles it, before staging
-        ds.where(SCAN).select(["id"]).to_table()
+        query(ds)
+        before = [metrics.counter(c).value for c in COUNTERS]
         with trace.collect() as tr:
-            got = ds.where(SCAN).select(["id"]).to_table()
-    assert len(got["id"]) > 0
-    filters = [s for s in tr.spans if s.name == "exec.filter"]
-    assert len(filters) == N_ROWS // 1024
-    for f in filters:
-        inside = [s for s in tr.spans if s.name.startswith("filter.")
+            got = query(ds)
+        moved = {c: metrics.counter(c).value - b
+                 for c, b in zip(COUNTERS, before)}
+    assert got > 0
+    spans = [s for s in tr.spans if s.name == outer]
+    assert len(spans) == groups
+    for f in spans:
+        inside = [s for s in tr.spans if s.name.startswith(layer + ".")
                   and s.tid == f.tid and f.ts <= s.ts
                   and s.ts + s.dur <= f.ts + f.dur]
-        assert sorted(s.name for s in inside) == [
-            "filter.fetch", "filter.launch", "filter.stage"]
         assert [s.name for s in sorted(inside, key=lambda s: s.ts)] == [
-            "filter.stage", "filter.launch", "filter.fetch"]
+            f"{layer}.stage", f"{layer}.launch", f"{layer}.fetch"]
         parts = sum(s.dur for s in inside)
         assert 0.8 * f.dur <= parts <= f.dur
+    packed = 0 if mixed_widths is not False else groups
+    assert moved == {
+        "bullion.filter.kernel_calls": groups if layer == "filter" else 0,
+        "bullion.aggregate.kernel_calls": groups if layer == "aggregate"
+        else 0,
+        "bullion.aggregate.packed_groups": packed,
+        "bullion.aggregate.host_groups": 0}
 
 
 def test_numpy_filter_path_has_no_round_trip_spans(table):
