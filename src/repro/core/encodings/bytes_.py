@@ -168,7 +168,12 @@ def encode_strings(strings: list[bytes], ctx: EncodeContext | None = None) -> by
     return struct.pack("<BQ", STRING_MAGIC, len(strings)) + _cat(off_blob, data_blob)
 
 
-def decode_strings(blob: bytes | memoryview) -> list[bytes]:
+def decode_strings(blob: bytes | memoryview,
+                   rows: np.ndarray | None = None) -> list[bytes]:
+    """The strings of a string column blob, in row order; only those at the
+    sorted in-blob row indices ``rows`` where given (None: every row). The
+    offsets and data decode whole either way; a ``bytes`` object is built
+    only for each row asked for."""
     from .base import decode_blob
     mv = memoryview(blob)
     magic, n = struct.unpack_from("<BQ", mv)
@@ -176,4 +181,9 @@ def decode_strings(blob: bytes | memoryview) -> list[bytes]:
     off_blob, data_blob = _split2(mv[9:])
     offsets = decode_blob(off_blob).astype(np.int64)
     data = decode_blob(data_blob).tobytes()
-    return [data[offsets[i]:offsets[i + 1]] for i in range(n)]
+    if rows is None:
+        starts, ends = offsets[:n].tolist(), offsets[1:n + 1].tolist()
+    else:
+        rows = np.asarray(rows, np.int64)
+        starts, ends = offsets[rows].tolist(), offsets[rows + 1].tolist()
+    return [data[a:b] for a, b in zip(starts, ends)]

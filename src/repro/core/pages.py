@@ -61,14 +61,18 @@ def decode_list_page(payload: bytes | memoryview) -> list[np.ndarray]:
     return [values[offsets[i]:offsets[i + 1]] for i in range(n)]
 
 
-def decode_page(ptype: int, payload: bytes | memoryview):
+def decode_page(ptype: int, payload: bytes | memoryview,
+                rows: np.ndarray | None = None):
+    """A page's rows, decoded. ``rows`` (sorted in-page row indices) limits
+    a STRING page to those rows; every other type ignores it and decodes
+    whole."""
     ptype = PageType(ptype)
     if ptype == PageType.SCALAR:
         return decode_scalar_page(payload)
     if ptype == PageType.LIST:
         return decode_list_page(payload)
     if ptype == PageType.STRING:
-        return decode_strings(payload)
+        return decode_strings(payload, rows)
     if ptype == PageType.SPARSE_DELTA:
         return sparse_delta.decode_page(payload)
     if ptype == PageType.MEDIA_REF:

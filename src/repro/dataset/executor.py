@@ -132,14 +132,16 @@ def decode_group(reader: "BullionReader", names: Sequence[str], group: int, *,
                  drop_deleted: bool = True, dequant: bool = True,
                  pages: Optional[Sequence[int]] = None,
                  align_raw: bool = False,
-                 masked_out: Optional[set] = None) -> dict:
+                 masked_out: Optional[set] = None,
+                 take: Optional[np.ndarray] = None) -> dict:
     """Decode one row group's columns via coalesced preads.
 
     ``pages`` restricts the read to a plan's surviving page ordinals (the
     same ordinals for every column — pages of one ordinal cover one row
     range group-wide). ``align_raw`` pads compact-deleted pages back to the
     raw row space (only meaningful with ``drop_deleted=False``); the default
-    keeps physical page content, which ``verify_deleted`` audits.
+    keeps physical page content, which ``verify_deleted`` audits. ``take``
+    returns each column at those rows only (see ``decode_pages``).
 
     Each stage is a distinct span (``decode.pread`` / ``decode.decode``,
     which names the chunk's ``encoding`` / ``decode.mask`` /
@@ -152,7 +154,7 @@ def decode_group(reader: "BullionReader", names: Sequence[str], group: int, *,
                         read_pages(reader, names, group, pages),
                         drop_deleted=drop_deleted, dequant=dequant,
                         pages=pages, align_raw=align_raw,
-                        masked_out=masked_out)
+                        masked_out=masked_out, take=take)
 
 
 def read_pages(reader: "BullionReader", names: Sequence[str], group: int,
@@ -171,14 +173,44 @@ def read_pages(reader: "BullionReader", names: Sequence[str], group: int,
     return raw
 
 
+def _page_shares(fv, pids: Sequence[int], take: np.ndarray,
+                 drop_deleted: bool) -> list[np.ndarray]:
+    """Each page's share of ``take`` (sorted indices into the row space the
+    pages ``pids`` decode to), as indices into that page's own decoded
+    rows. A page spans its row count, less its deleted rows under
+    ``drop_deleted``; raw-aligned, it spans its row count."""
+    page_rows = fv.arr(Sec.PAGE_ROWS, np.uint32)
+    sizes = []
+    for p in pids:
+        dv = fv.deletion_vector(p) if drop_deleted else None
+        sizes.append(int(page_rows[p]) - (0 if dv is None else int(dv.sum())))
+    ends = np.cumsum(sizes, dtype=np.int64)
+    cuts = np.searchsorted(take, ends)
+    return [take[a:b] - (e - n) for a, b, e, n
+            in zip([0, *cuts[:-1]], cuts, ends, sizes)]
+
+
 def decode_pages(reader: "BullionReader", names: Sequence[str], group: int,
                  raw: dict, *, drop_deleted: bool = True,
                  dequant: bool = True,
                  pages: Optional[Sequence[int]] = None,
                  align_raw: bool = False,
-                 masked_out: Optional[set] = None) -> dict:
+                 masked_out: Optional[set] = None,
+                 take: Optional[np.ndarray] = None) -> dict:
     """``decode_group`` after its pread: decode the pages ``read_pages``
-    returned."""
+    returned.
+
+    ``take`` (sorted indices into the row space the pages decode to, with
+    ``drop_deleted`` or ``align_raw``) returns each column at those rows
+    only. A string page builds just the strings of its share of ``take``
+    (string pages are erased in place, never compacted, so a share maps to
+    in-page rows through the page's kept positions); every other page
+    decodes whole, is masked or re-aligned as without ``take``, and is then
+    indexed. Counts the string pages decoded at a selection in
+    ``bullion.decode.selected_string_pages``."""
+    if take is not None and not (drop_deleted or align_raw):
+        raise ValueError("take needs drop_deleted or align_raw: a page's "
+                         "share of the physical row space is unknown")
     fv = reader.footer
     cols = [fv.column_index(n) for n in names]
     kinds = fv.arr(Sec.COL_KIND, np.uint8)
@@ -186,7 +218,9 @@ def decode_pages(reader: "BullionReader", names: Sequence[str], group: int,
     page_rows = fv.arr(Sec.PAGE_ROWS, np.uint32)
     out: dict = {}
 
-    def _dec(c: int, p: int):
+    def _dec(c: int, p: int, share: Optional[np.ndarray]):
+        """Page ``p`` decoded, and whether it is already cut to ``share``
+        (its rows in the output row space; None: every row)."""
         blob = raw.get(p)
         if blob is None:
             # the verification gate removed a quarantined page (corruption
@@ -196,20 +230,40 @@ def decode_pages(reader: "BullionReader", names: Sequence[str], group: int,
                 raise KeyError(p)
             if masked_out is not None:
                 masked_out.add(p)
-            return _mask_fill(fv, c, int(page_rows[p]))
-        return pages_mod.decode_page(int(flags[p]) & 0x7F, blob)
+            return _mask_fill(fv, c, int(page_rows[p])), False
+        ptype = int(flags[p]) & 0x7F
+        if share is None or ptype != PageType.STRING:
+            return pages_mod.decode_page(ptype, blob), False
+        dv = fv.deletion_vector(p) if drop_deleted else None
+        rows = share if dv is None else np.flatnonzero(~dv)[share]
+        return pages_mod.decode_page(ptype, blob, rows), True
 
     for name, c in zip(names, cols):
         pids = _chunk_page_ids(fv, group, c, pages)
+        shares = [None] * len(pids) if take is None \
+            else _page_shares(fv, pids, take, drop_deleted)
         sp = _trace.span("decode.decode", cat="decode", column=name,
                          pages=len(pids))
         if sp.enabled:
             sp.set(encoding=_encoding(flags, pids))
         with sp:
-            parts = [_dec(c, p) for p in pids]
+            parts, cut = [], []
+            for p, share in zip(pids, shares):
+                part, done = _dec(c, p, share)
+                parts.append(part)
+                cut.append(done)
+            if take is not None:
+                n_cut = sum(cut)
+                if n_cut:
+                    _metrics.counter(
+                        "bullion.decode.selected_string_pages").inc(n_cut)
+                if sp.enabled:
+                    sp.set(rows_out=len(take))
         if drop_deleted or align_raw:
             with _trace.span("decode.mask", cat="decode", column=name):
                 for i, p in enumerate(pids):
+                    if cut[i]:
+                        continue
                     if drop_deleted:
                         parts[i] = pages_mod.apply_dv(
                             parts[i], fv.deletion_vector(p),
@@ -217,6 +271,9 @@ def decode_pages(reader: "BullionReader", names: Sequence[str], group: int,
                     else:
                         parts[i] = _pad_raw(parts[i], fv.deletion_vector(p),
                                             int(page_rows[p]))
+        if take is not None:
+            parts = [part if done else _take(part, share)
+                     for part, done, share in zip(parts, cut, shares)]
         val = parts[0] if len(parts) == 1 else _concat(parts)
         if dequant and kinds[c] == int(ColKind.SCALAR):
             spec = reader.quant_spec(c)
@@ -700,13 +757,14 @@ def _execute_group_once(reader: "BullionReader", group: int, *,
     if rest:
         # drop_deleted=False means *raw row space*, always: compact-deleted
         # pages decode short, so every page is re-aligned (erased rows
-        # read 0) to keep row_ids and all columns the same length.
-        ptbl = decode_group(reader, rest, group,
-                            drop_deleted=drop_deleted, dequant=dequant,
-                            pages=pages, align_raw=not drop_deleted,
-                            masked_out=masked_out)
-        for name in rest:
-            out[name] = ptbl[name] if full else _take(ptbl[name], local)
+        # read 0) to keep row_ids and all columns the same length. The
+        # decode takes the surviving rows itself: string pages build only
+        # their strings.
+        out.update(decode_group(reader, rest, group,
+                                drop_deleted=drop_deleted, dequant=dequant,
+                                pages=pages, align_raw=not drop_deleted,
+                                masked_out=masked_out,
+                                take=None if full else local))
     return GroupResult(row_ids=raw_local, table=out)
 
 

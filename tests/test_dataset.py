@@ -523,3 +523,144 @@ def test_legacy_shims_delegate_to_plans(tmp_path):
         assert np.array_equal(
             ds.where(In("id", [55, 1700])).drop_deleted(False).row_ids(),
             [55, 1700])
+
+
+# ---------------------------------------------------------------------------
+# the payload pass decodes string pages at the surviving rows only
+# ---------------------------------------------------------------------------
+
+SELECT_COLS = ["id", "score", "qx", "url", "text"]
+SELECTED = "bullion.decode.selected_string_pages"
+
+
+def _write_strings(d, *, page_rows, n_shards=2, rows_each=1000):
+    """Shards of 500-row groups over ``page_rows``-row pages; ``id`` is
+    clustered, so page zone maps prune on it."""
+    os.makedirs(d, exist_ok=True)
+    schema = [ColumnSpec("id", "int64"), ColumnSpec("score", "float32"),
+              ColumnSpec("qx", "float32", quant=QuantSpec(QuantMode.BF16)),
+              ColumnSpec("url", "string"), ColumnSpec("text", "string")]
+    for s in range(n_shards):
+        rng = np.random.default_rng(40 + s)
+        ids = np.arange(s * rows_each, (s + 1) * rows_each, dtype=np.int64)
+        w = BullionWriter(os.path.join(d, f"part-{s:04d}.bln"), schema,
+                          rows_per_group=500, page_rows=page_rows)
+        w.write_table({
+            "id": ids,
+            "score": rng.random(rows_each).astype(np.float32),
+            "qx": rng.normal(size=rows_each).astype(np.float32),
+            "url": [b"https://img.example.com/%d/%x.jpg" % (i % 89, i * 7919)
+                    for i in ids],
+            "text": [b"" if i % 6 == 0 else b"caption %d " % i * int(i % 5)
+                     for i in ids]})
+        w.close()
+
+
+def _delete(level):
+    return lambda d: delete_rows(os.path.join(d, "part-0000.bln"),
+                                 np.arange(40, 460, 3), level=level)
+
+
+def _quarantine_url_page(d):
+    from repro.core import integrity
+    from repro.core.footer import read_footer
+    from repro.dataset import clear_footer_cache
+    path = os.path.join(d, "part-0001.bln")
+    fv, _ = read_footer(path)
+    page = fv.chunk_pages(1, fv.column_index("url"))[0] + 1
+    off, size = fv.page_extent(page)
+    with open(path, "r+b") as f:
+        f.seek(off + size // 2)
+        b = f.read(1)
+        f.seek(off + size // 2)
+        f.write(bytes([b[0] ^ 0xFF]))
+    clear_footer_cache()
+    integrity.set_verify_policy("full")
+    integrity.set_corruption_policy("mask")
+
+
+# name -> (page_rows, drop_deleted, file edit, predicate, pinned rows)
+STRING_SELECTIONS = {
+    "drop_deleted": (100, True, _delete(Compliance.LEVEL1),
+                     C("score") < 0.3, None),
+    "raw_row_space": (100, False, _delete(Compliance.LEVEL1),
+                      C("score") < 0.3, None),
+    "erased_in_place": (100, False, _delete(Compliance.LEVEL2),
+                        C("score") < 0.3, None),
+    "pruned_pages": (100, True, None,
+                     (C("id") >= 230) & (C("id") < 1470) & (C("score") < 0.5),
+                     None),
+    "with_rows": (100, True, None, None,
+                  np.asarray([0, 99, 100, 333, 499, 500, 1234, 1999])),
+    "quarantined_page": (100, True, _quarantine_url_page,
+                         C("score") < 0.4, None),
+    "pages_of_64": (64, True, None, C("score") < 0.3, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STRING_SELECTIONS))
+def test_selected_string_decode_matches_full_decode(tmp_path, case):
+    """A filtered read equals the full decode then the take, in value,
+    order and type; each string page read at a selection is counted,
+    and a read with no selection counts none."""
+    from repro.core import integrity
+    from repro.core.integrity import QUARANTINE
+    from repro.dataset.executor import _take
+    from repro.obs import metrics, trace
+    from repro.scan.predicate import evaluate
+    page_rows, drop, edit, pred, rows = STRING_SELECTIONS[case]
+    d = str(tmp_path / "shards")
+    _write_strings(d, page_rows=page_rows)
+    counted = metrics.counter(SELECTED)
+    try:
+        if edit is not None:
+            edit(d)
+        with dataset(d) as ds:
+            ds = ds.drop_deleted(drop)
+            before = counted.value
+            full = ds.select(SELECT_COLS).to_table()
+            full_ids = ds.row_ids()
+            assert counted.value == before
+            q = ds.select(SELECT_COLS)
+            keep = np.ones(len(full_ids), bool)
+            if pred is not None:
+                q = q.where(pred)
+                keep &= evaluate(pred, full)
+            if rows is not None:
+                q = q.with_rows(rows)
+                keep &= np.isin(full_ids, rows)
+            keep = np.flatnonzero(keep)
+            assert 0 < len(keep) < len(full_ids)
+            tasks = q.tasks()
+            before = counted.value
+            with trace.collect() as tracer:
+                got = q.to_table()
+            masked = len(QUARANTINE.lookup(os.path.join(d, "part-0001.bln"),
+                                           ds._source.footer(1)))
+            assert masked == (case == "quarantined_page")
+            assert counted.value - before == sum(
+                len(t.pages) if t.pages is not None
+                else len(ds._source.footer(t.shard).chunk_page_rows(t.group, 0))
+                for t in tasks) * 2 - masked
+            assert np.array_equal(q.row_ids(), full_ids[keep])
+        assert list(got) == SELECT_COLS
+        for name in SELECT_COLS:
+            want = _take(full[name], keep)
+            if isinstance(want, np.ndarray):
+                assert got[name].dtype == want.dtype
+                assert np.array_equal(got[name], want)
+            else:
+                assert got[name] == want
+                assert all(type(v) is bytes for v in got[name])
+        for name in ("url", "text"):
+            assert sum(sp.args["rows_out"] for sp in tracer.spans
+                       if sp.name == "decode.decode"
+                       and sp.args["column"] == name) == len(keep)
+        if case == "pruned_pages":
+            assert any(t.pages is not None for t in tasks)
+        if case == "quarantined_page":
+            assert b"" in got["url"]
+    finally:
+        integrity.set_verify_policy(None)
+        integrity.set_corruption_policy(None)
+        QUARANTINE.clear()

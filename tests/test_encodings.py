@@ -1,6 +1,8 @@
 """Property-based tests for the cascading encoding framework (§2.6) and the
 per-encoding deletion-masking rules (§2.1)."""
 
+import struct
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -8,6 +10,8 @@ from hypothesis import given, settings, strategies as st
 from repro.core.encodings import (BY_NAME, EncodeContext, blob_encoding_name,
                                   decode_blob, decode_strings, encode_array,
                                   encode_strings, mask_blob)
+from repro.core.encodings.base import frame, unframe
+from repro.core.encodings.numeric import _split2
 
 DTYPES = [np.int64, np.int32, np.uint32, np.uint64, np.int16, np.uint8]
 
@@ -121,6 +125,65 @@ def test_native_mask_in_place(enc_name):
 def test_strings_roundtrip():
     strings = [b"http://example.com/%d" % i for i in range(200)] + [b"", b"\xff" * 5]
     assert decode_strings(encode_strings(strings)) == strings
+
+
+def _string_blob(strings, data_frame):
+    """A string column blob whose data bytes are framed by ``data_frame``."""
+    from repro.core.encodings.bytes_ import STRING_MAGIC
+    from repro.core.encodings.numeric import _cat
+    lens = np.asarray([len(s) for s in strings], np.int64)
+    offsets = np.concatenate([[0], np.cumsum(lens)]).astype(np.int64)
+    data = data_frame(b"".join(strings))
+    assert data is not None
+    return struct.pack("<BQ", STRING_MAGIC, len(strings)) \
+        + _cat(encode_array(offsets), data)
+
+
+def _stored(data):
+    raw = BY_NAME["raw_bytes"]
+    return frame(raw.eid, struct.pack("<QB", len(data), raw.STORED), data)
+
+
+STRING_DATA = {
+    "raw_bytes-zstd": lambda d: BY_NAME["raw_bytes"].encode(d, EncodeContext()),
+    "raw_bytes-stored": _stored,
+    "fsst_lite": lambda d: BY_NAME["fsst_lite"].encode(d, EncodeContext()),
+}
+
+STRING_ROWS = {
+    "empty": lambda n, rng: np.zeros(0, np.int64),
+    "one": lambda n, rng: np.asarray([7]),
+    "last": lambda n, rng: np.asarray([n - 1]),
+    "2pct": lambda n, rng: np.sort(rng.choice(n, n // 50, replace=False)),
+    "all": lambda n, rng: np.arange(n),
+}
+
+
+@pytest.mark.parametrize("rows", sorted(STRING_ROWS))
+@pytest.mark.parametrize("data", sorted(STRING_DATA))
+def test_decode_strings_rows(data, rows):
+    """Decoding at sorted rows gives the full decode's strings at those
+    rows, in order, for every data encoding; empty strings included."""
+    strings = [b"" if i % 11 == 0 else
+               b"https://img.example.com/%d/photo-%s.jpg" % (i % 97,
+                                                             b"x" * (i % 13))
+               + (b"\xf3\xff" if i % 29 == 0 else b"")
+               for i in range(800)]
+    strings[-1] = b""
+    blob = _string_blob(strings, STRING_DATA[data])
+    data_blob = _split2(memoryview(blob)[9:])[1]
+    name, _, codec = data.partition("-")
+    assert blob_encoding_name(data_blob) == name
+    if codec:
+        raw = BY_NAME["raw_bytes"]
+        header = unframe(data_blob)[1]
+        assert header[8] == {"zstd": raw.ZSTD, "stored": raw.STORED}[codec]
+    full = decode_strings(blob)
+    assert full == strings
+    sel = STRING_ROWS[rows](len(strings), np.random.default_rng(3))
+    got = decode_strings(blob, sel)
+    assert got == [full[i] for i in sel]
+    assert all(type(s) is bytes for s in got)
 
 
 def test_cascade_never_worse_than_trivial():
